@@ -64,7 +64,8 @@ def bench_point(ranks, dup):
     cfg = dataclasses.replace(base, num_layers=2, moe=dataclasses.replace(
         base.moe, d_ff_expert=2048, duplication_slots=dup))
     E = cfg.moe.num_experts
-    mesh = jax.make_mesh((8 // ranks, ranks), ("data", "model"))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((8 // ranks, ranks), ("data", "model"))
     rt = Runtime(mesh=mesh, ep=True, ep_ranks=ranks, use_duplication=True)
     params = init_model(jax.random.PRNGKey(0), cfg)
     experts = params["layers"]["moe"]["experts"]
@@ -132,7 +133,8 @@ def bench_overlap(ranks, dup):
     cfg = dataclasses.replace(base, num_layers=2, moe=dataclasses.replace(
         base.moe, d_ff_expert=2048, duplication_slots=dup))
     E = cfg.moe.num_experts
-    mesh = jax.make_mesh((8 // ranks, ranks), ("data", "model"))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((8 // ranks, ranks), ("data", "model"))
     rt = Runtime(mesh=mesh, ep=True, ep_ranks=ranks, use_duplication=True)
     params = init_model(jax.random.PRNGKey(0), cfg)
     experts = params["layers"]["moe"]["experts"]
@@ -250,7 +252,8 @@ def run(verbose: bool = True, smoke: bool = None):
     prog = textwrap.dedent(_SUB).format(combos=combos, iters=iters)
     out = subprocess.run([sys.executable, "-c", prog], capture_output=True,
                          text=True, timeout=1800,
-                         env=dict(os.environ, PYTHONPATH=src_root))
+                         env=dict(os.environ, PYTHONPATH=src_root,
+                                  JAX_PLATFORMS="cpu"))
     if out.returncode != 0:
         raise RuntimeError(f"bench subprocess failed:\n{out.stderr[-2000:]}")
     doc_in = json.loads(out.stdout.strip().splitlines()[-1])

@@ -23,7 +23,9 @@ from repro.models.transformer import init_model
 from repro.serve import ServeEngine, ServeConfig
 from repro.data.synthetic import token_batches
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+from repro.launch.mesh import make_mesh
+
+mesh = make_mesh((2, 4), ("data", "model"))
 cfg = get_config("mixtral-8x7b").reduced()
 params = init_model(jax.random.PRNGKey(0), cfg)
 out = {}
@@ -49,7 +51,8 @@ def run(verbose: bool = True):
     res = subprocess.run(
         [sys.executable, "-c", textwrap.dedent(_BODY)],
         capture_output=True, text=True, timeout=1200,
-        env=dict(os.environ, PYTHONPATH=os.path.join(root, "src")))
+        env=dict(os.environ, PYTHONPATH=os.path.join(root, "src"),
+                 JAX_PLATFORMS="cpu"))
     if res.returncode != 0:
         raise RuntimeError(res.stderr[-2000:])
     data = json.loads(res.stdout.strip().splitlines()[-1])

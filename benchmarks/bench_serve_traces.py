@@ -107,7 +107,9 @@ from repro.obs import SpanTracer
 from repro.serve import ContinuousConfig, ContinuousEngine
 from repro.serve.scheduler import ServeRequest
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+from repro.launch.mesh import make_mesh
+
+mesh = make_mesh((2, 4), ("data", "model"))
 cfg = get_config("mixtral-8x7b").reduced()
 params = init_model(jax.random.PRNGKey(0), cfg)
 ccfg = ContinuousConfig(max_slots=4, prefill_len=32, block_size=16,
@@ -168,7 +170,9 @@ from repro.models.transformer import init_model
 from repro.serve import ContinuousConfig, ContinuousEngine
 from repro.serve.scheduler import ServeRequest
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+from repro.launch.mesh import make_mesh
+
+mesh = make_mesh((2, 4), ("data", "model"))
 base = get_config("mixtral-8x7b").reduced()
 cfg = dataclasses.replace(base, moe=dataclasses.replace(
     base.moe, capacity_factor=0.5, duplication_slots=1))
@@ -238,7 +242,9 @@ from repro.serve import ContinuousConfig
 from repro.sweep.workloads import build_workload
 from repro.workloads import to_serve_requests
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+from repro.launch.mesh import make_mesh
+
+mesh = make_mesh((2, 4), ("data", "model"))
 cfg = get_config("mixtral-8x7b").reduced()
 params = init_model(jax.random.PRNGKey(0), cfg)
 ccfg = ContinuousConfig(max_slots=4, prefill_len=32, block_size=8,
@@ -317,7 +323,8 @@ def _run_fleet_ab(attempts: int = 2) -> dict:
             out = subprocess.run(
                 [sys.executable, "-c", textwrap.dedent(_FLEET_SUB)],
                 capture_output=True, text=True, timeout=1500,
-                env=dict(os.environ, PYTHONPATH=src_root))
+                env=dict(os.environ, PYTHONPATH=src_root,
+                         JAX_PLATFORMS="cpu"))
         except subprocess.TimeoutExpired as e:
             last = f"timed out after {e.timeout}s"
             continue
@@ -339,7 +346,8 @@ def _run_resched_ab(attempts: int = 2) -> dict:
             out = subprocess.run(
                 [sys.executable, "-c", textwrap.dedent(_RESCHED_SUB)],
                 capture_output=True, text=True, timeout=900,
-                env=dict(os.environ, PYTHONPATH=src_root))
+                env=dict(os.environ, PYTHONPATH=src_root,
+                         JAX_PLATFORMS="cpu"))
         except subprocess.TimeoutExpired as e:
             last = f"timed out after {e.timeout}s"
             continue
@@ -404,7 +412,7 @@ def _run_meshed(trace_out: str) -> dict:
     out = subprocess.run(
         [sys.executable, "-c", textwrap.dedent(_MESHED_SUB)],
         capture_output=True, text=True, timeout=1800,
-        env=dict(os.environ, PYTHONPATH=src_root,
+        env=dict(os.environ, PYTHONPATH=src_root, JAX_PLATFORMS="cpu",
                  REPRO_TRACE_OUT=trace_out))
     if out.returncode != 0:
         raise RuntimeError(

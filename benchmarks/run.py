@@ -72,16 +72,12 @@ def run_meta() -> dict:
             timespec="seconds"),
         "python": platform.python_version(),
     }
-    try:
-        import jax
-        dev = jax.devices()[0]
-        meta.update(jax_version=jax.__version__,
-                    backend=jax.default_backend(),
-                    device_kind=dev.device_kind,
-                    device_count=jax.device_count())
-    except Exception as e:                       # keep the harness going
-        meta.update(jax_version="unavailable", backend=str(e)[:80],
-                    device_kind="unknown", device_count=0)
+    import jax
+    dev = jax.devices()[0]
+    meta.update(jax_version=jax.__version__,
+                backend=jax.default_backend(),
+                device_kind=dev.device_kind,
+                device_count=jax.device_count())
     return meta
 
 
@@ -109,6 +105,8 @@ def main(argv=None) -> int:
             return 2
         del argv[i:i + 2]
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     # import AFTER the env flag so modules can read it at import time too
     from benchmarks import (bench_appendix_c, bench_dispatch,
                             bench_dup_overhead, bench_fig4, bench_fig6,

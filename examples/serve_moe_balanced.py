@@ -31,6 +31,7 @@ import numpy as np
 from repro.configs.registry import get_config
 from repro.core.predictors import ConditionalProbabilityModel, accuracy
 from repro.data.synthetic import make_routing_trace
+from repro.launch.mesh import make_dev_mesh
 from repro.models.transformer import init_model
 from repro.serve import BatchScheduler, Request, ServeConfig, ServeEngine
 
@@ -38,7 +39,7 @@ BATCH, SEQ, NEW, REQUESTS = 8, 64, 4, 24
 
 
 def main():
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_dev_mesh(2, 4)
     cfg = get_config("mixtral-8x7b").reduced()
     params = init_model(jax.random.PRNGKey(0), cfg)
     print(f"{cfg.name}: E={cfg.moe.num_experts} top-{cfg.moe.top_k} on "

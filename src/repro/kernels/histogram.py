@@ -39,7 +39,7 @@ def _kernel(idx_ref, o_ref, *, num_classes: int, valid: int, bn: int):
 
 @functools.partial(jax.jit, static_argnames=("num_classes", "bn", "interpret"))
 def histogram(idx, num_classes: int, *, bn: int = DEFAULT_BN,
-              interpret: bool = True):
+              interpret: bool):
     """idx: (N,) int32 in [0, num_classes) -> counts (num_classes,) int32."""
     N = idx.shape[0]
     bn = min(bn, max(N, 8))
@@ -58,7 +58,7 @@ def histogram(idx, num_classes: int, *, bn: int = DEFAULT_BN,
 
 
 def histogram_offsets(idx, num_classes: int, *, bn: int = DEFAULT_BN,
-                      interpret: bool = True):
+                      interpret: bool):
     """Class histogram plus its exclusive prefix sum (slot start offsets).
 
     The sort-based dispatch packer consumes exactly this pair: counts give

@@ -11,7 +11,7 @@ so the (tokens_per_slot, d_ff) intermediate never round-trips HBM:
 
 Block shapes are multiples of (8, 128) so both matmuls keep the MXU fed;
 the f-loop accumulates into a VMEM f32 scratch, written once at f == F-1.
-Validated against ref.moe_gemm_ref with interpret=True on CPU.
+Validated against ref.moe_gemm_ref in interpret mode on CPU.
 """
 
 from __future__ import annotations
@@ -25,6 +25,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 DEFAULT_BT = 128       # token-block (second-minor >= 8)
 DEFAULT_BF = 512       # d_ff block (lane multiple of 128)
+VMEM_HEADROOM = 4 << 20   # Mosaic internal scratch on top of the blocks
 
 
 def _kernel(x_ref, wg_ref, wu_ref, wd_ref, o_ref, acc_ref, *,
@@ -59,7 +60,7 @@ def _kernel(x_ref, wg_ref, wu_ref, wd_ref, o_ref, acc_ref, *,
                    static_argnames=("activation", "bt", "bf", "interpret"))
 def moe_gemm(x, w_gate, w_up, w_down, *, activation: str = "swiglu",
              bt: int = DEFAULT_BT, bf: int = DEFAULT_BF,
-             interpret: bool = True):
+             interpret: bool):
     """x: (S, T, d); w_gate/w_up: (S, d, F); w_down: (S, F, d) -> (S, T, d).
 
     T and F are padded to block multiples internally (zero padding is
@@ -79,6 +80,11 @@ def moe_gemm(x, w_gate, w_up, w_down, *, activation: str = "swiglu",
         w_down = jnp.pad(w_down, ((0, 0), (0, pf), (0, 0)))
     Tp, Fp = T + pt, F + pf
     nf = Fp // bf
+    # double-buffered x/wg/wu/wd/out blocks + the f32 accumulator: at
+    # Mixtral widths (d 4096, bf 512) that is ~30 MB, past the 16 MB
+    # default scoped-VMEM limit, so the kernel asks for what it uses
+    isz, wsz = x.dtype.itemsize, w_up.dtype.itemsize
+    vmem = 2 * (2 * bt * d * isz + 3 * d * bf * wsz) + bt * d * 4
 
     out = pl.pallas_call(
         functools.partial(_kernel, activation=activation, nf=nf),
@@ -92,6 +98,8 @@ def moe_gemm(x, w_gate, w_up, w_down, *, activation: str = "swiglu",
         out_specs=pl.BlockSpec((1, bt, d), lambda s, t, f: (s, t, 0)),
         out_shape=jax.ShapeDtypeStruct((S, Tp, d), x.dtype),
         scratch_shapes=[pltpu.VMEM((bt, d), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=vmem + VMEM_HEADROOM),
         interpret=interpret,
     )(x, w_gate, w_up, w_down)
     return out[:, :T]

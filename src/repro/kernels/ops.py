@@ -1,8 +1,8 @@
 """jit'd public wrappers for the Pallas kernels.
 
-On CPU (this container) kernels run with ``interpret=True`` — the kernel
-body executes in Python per grid step, validating the exact TPU program.
-On a real TPU backend ``interpret`` flips to False automatically.
+``_interpret`` is the one place that decides interpretation: off the TPU
+the kernel body executes in Python per grid step (validating the exact TPU
+program); on a TPU backend the kernels compile through Mosaic.
 """
 
 from __future__ import annotations
@@ -56,10 +56,31 @@ def rg_lru_scan(a, b, h0):
 
 
 def paged_decode_attention(q, k_pool, v_pool, block_tables, lengths, *,
-                           window: int = 0):
+                           window: int = 0, mesh=None):
     """Fused paged GQA decode over the shared KV block pool — gather +
     online-softmax in one pass, no (B, M*bs, K, hd) intermediate (see
-    `repro.kernels.paged_attention`). q: (B, K, G, hd)."""
-    return _pa.paged_decode_attention(q, k_pool, v_pool, block_tables,
-                                      lengths, window=window,
-                                      interpret=_interpret())
+    `repro.kernels.paged_attention`). q: (B, K, G, hd).
+
+    ``mesh``: the kernel runs under ``shard_map`` over it (Mosaic kernels
+    cannot be partitioned automatically), sharded like the decode
+    activations: slots (q, tables, lengths) over the batch axes when they
+    divide evenly (``sharding.batch_axes_for``), the KV-head dim like the
+    attention projections (``sharding.kv_head_axis``). The pool is split
+    by head only: any slot's blocks may sit anywhere in it."""
+    def kernel(q_, k_, v_, t_, l_):
+        return _pa.paged_decode_attention(q_, k_, v_, t_, l_, window=window,
+                                          interpret=_interpret())
+
+    if mesh is None:
+        return kernel(q, k_pool, v_pool, block_tables, lengths)
+    from jax.sharding import PartitionSpec as P
+    from repro.sharding import batch_axes_for, kv_head_axis, paged_pool_spec
+    B, K = q.shape[:2]
+    b = batch_axes_for(mesh, B)
+    q_spec = P(b, kv_head_axis(mesh, K), None, None)
+    kv_spec = paged_pool_spec(mesh, K, stacked=False)
+    return jax.shard_map(kernel, mesh=mesh,
+                         in_specs=(q_spec, kv_spec, kv_spec, P(b, None),
+                                   P(b)),
+                         out_specs=q_spec, check_vma=False)(
+        q, k_pool, v_pool, block_tables, lengths)

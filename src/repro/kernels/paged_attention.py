@@ -95,7 +95,7 @@ def _decode_kernel(tables_ref, lengths_ref, q_ref, k_ref, v_ref, o_ref,
 
 @functools.partial(jax.jit, static_argnames=("window", "interpret"))
 def paged_decode_attention(q, k_pool, v_pool, block_tables, lengths, *,
-                           window: int = 0, interpret: bool = True):
+                           window: int = 0, interpret: bool):
     """Fused paged GQA decode. q: (B, K, G, hd) — query heads grouped by
     their KV head; k_pool/v_pool: (N_blocks, bs, K, hd) shared pool
     (block 0 reserved null); block_tables: (B, M) int32; lengths: (B,)

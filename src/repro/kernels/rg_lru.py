@@ -47,7 +47,7 @@ def _kernel(a_ref, b_ref, h0_ref, o_ref, hl_ref, *, ns: int, bs: int):
 
 @functools.partial(jax.jit, static_argnames=("bd", "bs", "interpret"))
 def rg_lru_scan(a, b, h0, *, bd: int = DEFAULT_BD, bs: int = DEFAULT_BS,
-                interpret: bool = True):
+                interpret: bool):
     """a, b: (B, S, D) f32; h0: (B, D) f32 -> (h_all (B,S,D), h_last (B,D)).
 
     h_t = a_t * h_{t-1} + b_t per independent channel.

@@ -64,7 +64,7 @@ def _kernel(logits_ref, idx_ref, gates_ref, probs_ref, lse_ref, counts_ref, *,
 
 @functools.partial(jax.jit, static_argnames=("top_k", "bn", "interpret"))
 def fused_topk_route(logits, top_k: int, *, bn: int = DEFAULT_BN,
-                     interpret: bool = True):
+                     interpret: bool):
     """logits: (T, E) -> (idx (T,K) i32, gates (T,K) f32 UN-normalised,
     probs (T,E) f32, lse (T,) f32, counts (E,) i32).
 

@@ -17,7 +17,9 @@ import numpy as np
 from repro.configs.registry import get_config
 from repro.core.predictors import ConditionalProbabilityModel
 from repro.data.synthetic import make_routing_trace, token_batches
-from repro.models.transformer import init_model
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.mesh import make_dev_mesh
+from repro.models.transformer import init_params
 from repro.serve import BatchScheduler, Request, ServeConfig, ServeEngine
 
 
@@ -39,6 +41,7 @@ def main(argv=None) -> int:
                     help="write a Chrome trace-event JSON of the run "
                          "(open in Perfetto / chrome://tracing)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:
@@ -46,11 +49,10 @@ def main(argv=None) -> int:
 
     mesh, ep_ranks = None, 1
     if args.data_mesh and args.model_mesh:
-        mesh = jax.make_mesh((args.data_mesh, args.model_mesh),
-                             ("data", "model"))
+        mesh = make_dev_mesh(args.data_mesh, args.model_mesh)
         ep_ranks = args.model_mesh
 
-    params = init_model(jax.random.PRNGKey(args.seed), cfg)
+    params = init_params(jax.random.PRNGKey(args.seed), cfg, mesh=mesh)
 
     predictor = None
     if args.strategy == "token_to_expert" and cfg.is_moe:

@@ -20,7 +20,7 @@ from repro.configs.base import InputShape, ModelConfig
 from repro.core.placement import identity_plan, stack_plans
 from repro.models.transformer import Runtime, init_cache, init_model
 from repro.optim.adamw import adamw_init
-from repro.sharding import batch_axes, param_specs
+from repro.sharding import batch_axes, batch_axes_for, param_specs
 
 # Sliding window applied to full-attention archs for long_500k decode
 # (Mixtral's own 4K window — paper-faithful; DESIGN.md Sec 4).
@@ -74,13 +74,6 @@ def plan_args(cfg: ModelConfig, ep_ranks: int):
 # inputs
 # ---------------------------------------------------------------------------
 
-def _batch_axes_for(mesh: Mesh, B: int):
-    """Batch axes, dropped to replication when B isn't evenly divisible
-    (e.g. long-context decode with global_batch=1)."""
-    b = batch_axes(mesh)
-    n = int(np.prod([mesh.shape[a] for a in b])) if b else 1
-    return b if b and B % n == 0 else ()
-
 def input_specs(cfg: ModelConfig, shape: InputShape, mesh: Mesh,
                 *, per_device_batch: Optional[int] = None) -> Dict:
     """ShapeDtypeStructs for the step inputs of (arch, input-shape).
@@ -89,7 +82,7 @@ def input_specs(cfg: ModelConfig, shape: InputShape, mesh: Mesh,
     decode: {"tokens": (B, 1)} — the cache is separate (abstract_cache).
     """
     B, S = shape.global_batch, shape.seq_len
-    b = _batch_axes_for(mesh, B)
+    b = batch_axes_for(mesh, B)
     bspec = NamedSharding(mesh, P(b, None))
 
     if shape.kind == "decode":
@@ -144,7 +137,7 @@ def abstract_opt_state(params_struct, param_spec_tree, mesh: Mesh,
 def cache_specs(cfg: ModelConfig, cache_struct, mesh: Mesh, B: int):
     """PartitionSpec tree matching init_cache's structure."""
     m = mesh.shape["model"]
-    b = _batch_axes_for(mesh, B)     # batch axis dropped when not divisible
+    b = batch_axes_for(mesh, B)     # batch axis dropped when not divisible
 
     def leaf_spec(path: str, leaf):
         nd = len(leaf.shape)

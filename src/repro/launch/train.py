@@ -18,6 +18,8 @@ import numpy as np
 
 from repro.configs.registry import get_config
 from repro.data.synthetic import token_batches
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.mesh import make_dev_mesh
 from repro.launch.specs import plan_args
 from repro.models.transformer import Runtime, init_model
 from repro.optim.adamw import adamw_init
@@ -51,6 +53,7 @@ def main(argv=None) -> int:
                     help="devices for a (data, model) dev mesh (0 = single)")
     ap.add_argument("--model-mesh", type=int, default=0)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:
@@ -59,8 +62,7 @@ def main(argv=None) -> int:
     mesh = None
     rt = Runtime()
     if args.data_mesh and args.model_mesh:
-        mesh = jax.make_mesh((args.data_mesh, args.model_mesh),
-                             ("data", "model"))
+        mesh = make_dev_mesh(args.data_mesh, args.model_mesh)
         rt = Runtime(mesh=mesh, ep=cfg.is_moe, ep_ranks=args.model_mesh,
                      use_duplication=False)
 

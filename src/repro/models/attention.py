@@ -225,7 +225,7 @@ def gqa_decode_multi(params, cfg: ModelConfig, x, cache, lengths, *, window=0):
 
 
 def gqa_decode_paged(params, cfg: ModelConfig, x, pool, block_tables, lengths,
-                     *, window: int = 0):
+                     *, window: int = 0, mesh=None):
     """Continuous-batching decode over a paged KV block pool.
 
     pool k/v: (N_blocks, block, K, hd) — one shared fixed-shape pool, so
@@ -243,7 +243,8 @@ def gqa_decode_paged(params, cfg: ModelConfig, x, pool, block_tables, lengths,
     ``cfg.paged_attn_impl`` selects the attention path: "fused" runs the
     Pallas kernel straight off the pool (no gathered intermediate);
     "gather" materializes the logical view and runs the identical blockwise
-    online-softmax in pure jnp (fp32 bit-exact oracle).
+    online-softmax in pure jnp (fp32 bit-exact oracle). ``mesh``: the
+    serving mesh, under which the fused kernel runs in a ``shard_map``.
     """
     from repro.kernels import ops as _kernel_ops
     from repro.kernels import ref as _kernel_ref
@@ -272,13 +273,22 @@ def gqa_decode_paged(params, cfg: ModelConfig, x, pool, block_tables, lengths,
     impl = getattr(cfg, "paged_attn_impl", "fused")
     if impl == "fused":
         out = _kernel_ops.paged_decode_attention(
-            qg, k_pool, v_pool, block_tables, lengths, window=window)
+            qg, k_pool, v_pool, block_tables, lengths, window=window,
+            mesh=mesh)
     else:
         # gather each slot's view: (B, M, bs, K, hd) -> (B, M*bs, K, hd)
         k_view = k_pool[block_tables].reshape(B, -1, K, hd)
         v_view = v_pool[block_tables].reshape(B, -1, K, hd)
         out = _kernel_ref.paged_decode_ref(qg, k_view, v_view, lengths,
                                            window=window, block_size=bs)
+    if mesh is not None:
+        # pin the pool layout so every step sees the same input sharding
+        # (each distinct layout would be its own compiled program)
+        from jax.sharding import NamedSharding
+        from repro.sharding import paged_pool_spec
+        sh = NamedSharding(mesh, paged_pool_spec(mesh, K, stacked=False))
+        k_pool = jax.lax.with_sharding_constraint(k_pool, sh)
+        v_pool = jax.lax.with_sharding_constraint(v_pool, sh)
     pool = {**pool, "k": k_pool, "v": v_pool}
     return dense(params["wo"], out.reshape(B, 1, -1)), pool
 

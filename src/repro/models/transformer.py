@@ -24,22 +24,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-try:                                      # jax >= 0.5 exports it at top level
-    from jax import shard_map as _shard_map
-except ImportError:                       # jax 0.4.x: experimental namespace
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-import inspect as _inspect
-_SHARD_MAP_PARAMS = _inspect.signature(_shard_map).parameters
-
-
-def shard_map(f, **kw):
-    """Version-portable shard_map: new jax names the replication-check knob
-    ``check_vma``; 0.4.x called it ``check_rep``."""
-    if "check_vma" in kw and "check_vma" not in _SHARD_MAP_PARAMS:
-        kw["check_rep"] = kw.pop("check_vma")
-    return _shard_map(f, **kw)
-
 from repro.configs.base import ModelConfig
 from repro.core.placement import PlacementPlan, identity_plan
 from repro.models import attention as attn
@@ -145,8 +129,19 @@ def _layer_kind(cfg: ModelConfig, layer_idx: int) -> str:
     return "attn"
 
 
-def init_model(key, cfg: ModelConfig):
+def init_model(key, cfg: ModelConfig, dtype=jnp.float32):
+    """Parameters in ``dtype``. Stacked layers are built one layer at a
+    time (``lax.map``) and cast per layer, so tracing this under jit never
+    needs more than one layer's worth of temporaries."""
     keys = jax.random.split(key, 8)
+
+    def cast(tree):
+        return jax.tree.map(lambda a: a.astype(dtype), tree)
+
+    def stack(layer_keys, kind, layer_cfg=cfg):
+        return jax.lax.map(
+            lambda k: cast(_init_layer(k, layer_cfg, kind)), layer_keys)
+
     params: Dict[str, Any] = {"embed": init_embedding(keys[0], cfg.vocab_size,
                                                       cfg.d_model)}
     params["final_norm"] = init_norm(cfg.norm, cfg.d_model)
@@ -161,8 +156,7 @@ def init_model(key, cfg: ModelConfig):
     else:
         kind = _layer_kind(cfg, 0)
         layer_keys = jax.random.split(keys[2], cfg.num_layers)
-        params["layers"] = jax.vmap(
-            lambda k: _init_layer(k, cfg, kind))(layer_keys)
+        params["layers"] = stack(layer_keys, kind)
 
     if cfg.is_encdec:
         enc = cfg.encoder
@@ -173,14 +167,29 @@ def init_model(key, cfg: ModelConfig):
             d_ff=enc.d_ff, moe=None, encoder=None, attention="gqa",
             head_dim=enc.d_model // enc.num_heads)
         ekeys = jax.random.split(keys[3], enc.num_layers)
-        params["enc_layers"] = jax.vmap(
-            lambda k: _init_layer(k, enc_cfg, "encoder"))(ekeys)
+        params["enc_layers"] = stack(ekeys, "encoder", enc_cfg)
         params["enc_norm"] = init_norm(cfg.norm, enc.d_model)
         # decoder layers get cross-attention
         dkeys = jax.random.split(keys[2], cfg.num_layers)
-        params["layers"] = jax.vmap(
-            lambda k: _init_layer(k, cfg, "decoder"))(dkeys)
-    return params
+        params["layers"] = stack(dkeys, "decoder")
+    return cast(params)
+
+
+def init_params(key, cfg: ModelConfig, *, mesh: Optional[Mesh] = None):
+    """Serving weights: ``init_model``'s values in bf16, built under jit
+    straight into their ``sharding.param_specs`` layout on ``mesh``
+    (unsharded without one). No device ever holds more than its share,
+    and float32 values exist only one layer at a time (``init_model``
+    casts per layer)."""
+    def build(k):
+        return init_model(k, cfg, jnp.bfloat16)
+
+    if mesh is None:
+        return jax.jit(build)(key)
+    from repro.sharding import make_shardings, param_specs
+    shapes = jax.eval_shape(build, key)
+    shardings = make_shardings(mesh, param_specs(shapes, mesh=mesh))
+    return jax.jit(build, out_shardings=shardings)(key)
 
 
 # ---------------------------------------------------------------------------
@@ -230,9 +239,9 @@ def init_cache(cfg: ModelConfig, rt: Runtime, batch: int, max_len: int,
 # MoE layer execution paths
 # ---------------------------------------------------------------------------
 
-def _moe_apply(layer_p, cfg: ModelConfig, x, rt: Runtime, plan_l,
-               predicted_l, decode: bool, token_weight=None,
-               slot_w_l=None, resched_l=None):
+def moe_apply(layer_p, cfg: ModelConfig, x, rt: Runtime, plan_l,
+              predicted_l, decode: bool, token_weight=None,
+              slot_w_l=None, resched_l=None, migration_l=None):
     """x: (B, S, d). Returns (y, expert_counts (E,), slot_counts, aux, z,
     dropped, overflow).
 
@@ -251,6 +260,14 @@ def _moe_apply(layer_p, cfg: ModelConfig, x, rt: Runtime, plan_l,
     for this layer (one ``repro.runtime.ReplicaStore`` layer slice) —
     sharded over the EP axis so dispatch reads replica weights from
     device memory instead of re-gathering a pool every step.
+
+    ``migration_l``: optional ``(ready, target_plan, back_weights)`` for
+    this layer during an overlapped migration (``MoEConfig.
+    overlap_migration``): once the layer's staged fill is ready, dispatch
+    reads the back buffer under the target plan, until then the live
+    pair. The ``lax.cond`` wraps the dispatch itself, not a choice of
+    weights, so neither buffer is copied (a cond that returns the chosen
+    weights materializes them: a store-sized temporary per step).
     """
     moe = cfg.moe
     B, S, d = x.shape
@@ -292,6 +309,7 @@ def _moe_apply(layer_p, cfg: ModelConfig, x, rt: Runtime, plan_l,
                and moe.d_ff_expert % n_tp == 0)
     if tp_mode:
         slot_w_l = None       # 2D expert sharding keeps the gather path
+        migration_l = None
     expert_specs = P("model", None, None)
     if decode:
         if tp_mode:
@@ -313,18 +331,30 @@ def _moe_apply(layer_p, cfg: ModelConfig, x, rt: Runtime, plan_l,
     router_impl = ("fused" if rt.use_kernel and moe.dispatch_impl == "sort"
                    else "dense")
 
-    def inner(x_blk, router_w, experts_w, plan, pred, w_blk, slot_blk, quota):
+    def inner(x_blk, router_w, experts_w, plan, pred, w_blk, slot_blk, quota,
+              mig):
         t = x_blk.reshape(-1, x_blk.shape[-1])
         router_out = route(router_w, moe, t, impl=router_impl)
-        y, stats = dispatch_fn(
-            t, router_out, experts_w, plan, moe,
-            axis_name=rt.ep_axis, ep_ranks=rt.ep_ranks,
-            activation=cfg.activation,
-            use_duplication=rt.use_duplication,
-            predicted_idx=pred.reshape(-1, moe.top_k) if pred is not None else None,
-            use_kernel=rt.use_kernel,
-            slot_weights=slot_blk,
-            resched_quota=quota)
+
+        def dispatch(plan, slots):
+            return dispatch_fn(
+                t, router_out, experts_w, plan, moe,
+                axis_name=rt.ep_axis, ep_ranks=rt.ep_ranks,
+                activation=cfg.activation,
+                use_duplication=rt.use_duplication,
+                predicted_idx=(pred.reshape(-1, moe.top_k)
+                               if pred is not None else None),
+                use_kernel=rt.use_kernel,
+                slot_weights=slots,
+                resched_quota=quota)
+
+        if mig is None:
+            y, stats = dispatch(plan, slot_blk)
+        else:
+            ready, tplan, back_blk = mig
+            y, stats = jax.lax.cond(ready,
+                                    lambda: dispatch(tplan, back_blk),
+                                    lambda: dispatch(plan, slot_blk))
         counts, slots = stats.expert_counts, stats.slot_counts
         aux, z, dropped = stats.aux_loss, stats.z_loss, stats.dropped
         overflow = stats.overflow
@@ -354,14 +384,15 @@ def _moe_apply(layer_p, cfg: ModelConfig, x, rt: Runtime, plan_l,
     w_spec = None if token_weight is None else P(*x_spec[:-1])
     slot_spec = None if slot_w_l is None else P("model", None, None)
     resched_spec = None if resched_l is None else P()
-    y, counts, slot_counts, aux, z, dropped, overflow = shard_map(
+    mig_spec = None if migration_l is None else (P(), plan_specs, slot_spec)
+    y, counts, slot_counts, aux, z, dropped, overflow = jax.shard_map(
         inner, mesh=mesh,
         in_specs=(x_spec, P(), expert_specs, plan_specs, pred_spec, w_spec,
-                  slot_spec, resched_spec),
+                  slot_spec, resched_spec, mig_spec),
         out_specs=(x_spec, P(), P(), P(), P(), P(), P()),
         check_vma=False,
     )(x, layer_p["moe"]["router"], layer_p["moe"]["experts"], plan_l,
-      predicted_l, token_weight, slot_w_l, resched_l)
+      predicted_l, token_weight, slot_w_l, resched_l, migration_l)
 
     if "shared" in layer_p["moe"]:
         y = y + ffn(layer_p["moe"]["shared"], x, cfg.activation)
@@ -384,7 +415,7 @@ def _zero_stats(cfg):
 def _attn_layer(layer_p, cfg, x, positions, rt, *, cache=None, cache_len=None,
                 mode="train", enc_out=None, plan_l=None, predicted_l=None,
                 block_tables=None, token_weight=None, slot_w_l=None,
-                resched_l=None):
+                resched_l=None, migration_l=None):
     """Generic attention+FFN layer for dense/moe/vlm/audio-decoder."""
     window = rt.window(cfg)
     h = apply_norm(cfg.norm, layer_p["ln1"], x)
@@ -415,7 +446,8 @@ def _attn_layer(layer_p, cfg, x, positions, rt, *, cache=None, cache_len=None,
             # one-pass kernel or the materialize-then-attend gather oracle
             a, sub = attn.gqa_decode_paged(layer_p["attn"], cfg, h, sub,
                                            block_tables, cache_len,
-                                           window=cfg.sliding_window)
+                                           window=cfg.sliding_window,
+                                           mesh=rt.mesh)
         elif cfg.attention == "mla":
             a, sub = attn.mla_decode(layer_p["attn"], cfg, h, sub, cache_len,
                                      window=window)
@@ -447,10 +479,10 @@ def _attn_layer(layer_p, cfg, x, positions, rt, *, cache=None, cache_len=None,
 
     h = apply_norm(cfg.norm, layer_p["ln2"], x)
     if cfg.is_moe:
-        y, counts, slots, aux, z, dropped, overflow = _moe_apply(
+        y, counts, slots, aux, z, dropped, overflow = moe_apply(
             layer_p, cfg, h, rt, plan_l, predicted_l,
             decode=(mode == "decode"), token_weight=token_weight,
-            slot_w_l=slot_w_l, resched_l=resched_l)
+            slot_w_l=slot_w_l, resched_l=resched_l, migration_l=migration_l)
         stats = (counts, slots, aux, z, dropped, overflow)
     else:
         y = ffn(layer_p["ffn"], h, cfg.activation)
@@ -553,17 +585,6 @@ def _logits(params, cfg: ModelConfig, x):
     if cfg.tie_embeddings:
         return unembed(params["embed"], h)
     return dense(params["lm_head"], h)
-
-
-def _migration_view(ready_l, plan_l, slot_l, tplan_l, back_l):
-    """Per-layer double-buffer select for overlapped migration: once a
-    layer's staged fill is READY, dispatch reads the back buffer under the
-    target plan row; until then it reads the live (old-plan) pair. A
-    ``lax.cond`` (not ``where``) so the unselected buffer is never
-    materialized — idle steps (ready all-False) cost nothing."""
-    return jax.lax.cond(ready_l,
-                        lambda: (tplan_l, back_l),
-                        lambda: (plan_l, slot_l))
 
 
 def forward(params, cfg: ModelConfig, batch, rt: Runtime, *, mode: str,
@@ -681,16 +702,14 @@ def forward(params, cfg: ModelConfig, batch, rt: Runtime, *, mode: str,
         def body(h, xs):
             (layer_p, cache_l, plan_l, pred_l, slot_l, back_l, ready_l,
              tplan_l, resched_l) = xs
-            if overlap:
-                plan_l, slot_l = _migration_view(ready_l, plan_l, slot_l,
-                                                 tplan_l, back_l)
             h = constrain_acts(h, rt, seq_shard)
             h, new_c, st = _attn_layer(
                 layer_p, cfg, h, positions, rt, cache=cache_l,
                 cache_len=cache_len, mode=mode, enc_out=enc_out,
                 plan_l=plan_l, predicted_l=pred_l,
                 block_tables=block_tables, token_weight=token_weight,
-                slot_w_l=slot_l, resched_l=resched_l)
+                slot_w_l=slot_l, resched_l=resched_l,
+                migration_l=(ready_l, tplan_l, back_l) if overlap else None)
             return constrain_acts(h, rt, seq_shard), (new_c, st)
 
         xs = (params["layers"], cache,

@@ -427,7 +427,7 @@ def ep_moe_ffn_replicated(
     """Decode-path EP dispatch: tokens are replicated over the model axis
     (decode batches are too small to shard over it). Each rank computes the
     (token, k) pairs assigned to ITS slots; a psum combines results. The
-    only dispatch communication is the (T, d) psum — appropriate for the
+    only dispatch communication is the (T * K, d) psum — appropriate for the
     latency-critical decode stage (paper Sec 2: balancing is secondary
     there, but duplication still helps the compute term).
 
@@ -495,12 +495,16 @@ def ep_moe_ffn_replicated(
         y2 = jnp.where(in_cap2[:, None],
                        ys2[jnp.minimum(dest2, n_slots * cap - 1)], 0.0)
         y_flat = y_flat + y2            # disjoint masks: miss vs in-cap
+    # Combine per (token, k) pair BEFORE the gate-weighted sum: each pair
+    # is computed on exactly one EP rank (zeros elsewhere), so the psum is
+    # exact and the served output does not depend on which copy of an
+    # expert took the pair — duplication never changes the tokens served.
+    # tp_axis ranks hold d_ff shards: their outputs are PARTIAL sums over
+    # f; one psum over (tp, ep) both combines f-partials and slot results.
+    y_flat = jax.lax.psum(y_flat, tuple(tp_axis) + (axis_name,) if tp_axis
+                          else axis_name)
     gates = router_out.gates.astype(x.dtype)
     y = (y_flat.reshape(T, K, d) * gates[..., None]).sum(axis=1)
-    # tp_axis ranks hold d_ff shards: their y's are PARTIAL sums over f;
-    # one psum over (tp, ep) both combines f-partials and slot results.
-    y = jax.lax.psum(y, tuple(tp_axis) + (axis_name,) if tp_axis
-                     else axis_name)
 
     counts = jnp.zeros((E,), jnp.float32).at[flat(router_out.expert_idx)].add(1.0)
     slot_counts = jnp.zeros((S,), jnp.int32).at[

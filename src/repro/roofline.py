@@ -364,8 +364,6 @@ def analyze(arch: str, shape, mesh_name: str, chips: int, compiled,
             cfg=None) -> RooflineReport:
     """Build a report from a jax ``compiled`` object."""
     cost = compiled.cost_analysis() or {}
-    if isinstance(cost, (list, tuple)):       # jax <= 0.4.x: list per device
-        cost = cost[0] if cost else {}
     flops = float(cost.get("flops", 0.0))
     hbm = float(cost.get("bytes accessed", 0.0))
     try:

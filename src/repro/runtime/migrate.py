@@ -5,12 +5,13 @@ changed slots: every rank contributes the entries whose source expert
 lives in its home shard, a psum broadcasts them (the only collective —
 bytes proportional to the chunk, not to the rank count), and each rank
 scatters the entries destined for its slot block into its store shard.
-All shapes are static, so a migration of any size is a sequence of
-identical step calls — zero recompiles, asserted by the engines'
-compile-count checks.
+The step DONATES its store buffer, so a fill updates it in place (chunk-
+sized writes, never a whole-store copy per call). All shapes are static,
+so a migration of any size is a sequence of identical step calls — zero
+recompiles, asserted by the engines' compile-count checks.
 
-``MigrationExecutor`` runs that sequence against a *copy* of the live
-buffers (double-buffering is free: jax arrays are immutable) under a
+``MigrationExecutor`` runs that sequence against a copy of the live
+buffers (the double buffer: one store-sized copy per migration) under a
 per-engine-step chunk budget; the engine keeps serving on the old plan +
 old store until ``tick`` reports the commit payload.
 
@@ -43,8 +44,9 @@ def make_migrate_step(mesh, *, num_experts: int, ep_ranks: int,
     valid) -> weights`` filling the described slots.
 
     weights: {name: (L, S, ...)} store buffers (sharded over ``ep_axis``
-    when ``mesh`` is given); experts: {name: (L, E, ...)} the home expert
-    stacks; descriptor arrays: (chunk,) replicated.
+    when ``mesh`` is given), DONATED — callers keep only the returned
+    buffers; experts: {name: (L, E, ...)} the home expert stacks;
+    descriptor arrays: (chunk,) replicated.
     ``mesh=None`` builds the single-device variant (tests / profiling).
     """
     e_loc, n_slots = plan_dims(num_experts, ep_ranks, dup_slots)
@@ -57,10 +59,9 @@ def make_migrate_step(mesh, *, num_experts: int, ep_ranks: int,
                 li = jnp.where(valid, layer, w.shape[0])    # invalid -> drop
                 out[k] = weights[k].at[li, dst_slot].set(full, mode="drop")
             return out
-        return jax.jit(step)
+        return jax.jit(step, donate_argnums=0)
 
     from jax.sharding import PartitionSpec as P
-    from repro.models.transformer import shard_map
 
     def inner(weights, experts, layer, dst_slot, src_expert, valid):
         rank = jax.lax.axis_index(ep_axis)
@@ -78,10 +79,16 @@ def make_migrate_step(mesh, *, num_experts: int, ep_ranks: int,
         return out
 
     blk = P(None, ep_axis)             # prefix spec: dim 1 = slots/experts
-    fn = shard_map(inner, mesh=mesh,
+    fn = jax.shard_map(inner, mesh=mesh,
                    in_specs=(blk, blk, P(), P(), P(), P()),
                    out_specs=blk, check_vma=False)
-    return jax.jit(fn)
+    return jax.jit(fn, donate_argnums=0)
+
+
+def copy_buffers(weights: Dict[str, jnp.ndarray]) -> Dict[str, jnp.ndarray]:
+    """Fresh device copies (same shardings) that a donating migrate step
+    may consume while the originals keep serving."""
+    return jax.tree.map(jnp.copy, weights)
 
 
 class MigrationExecutor:
@@ -116,7 +123,8 @@ class MigrationExecutor:
         """Stage a migration from the LIVE buffers toward ``target_plan``.
         Restarting while active abandons the partial back buffer (the live
         buffers were never touched, so no state is lost)."""
-        self._back = dict(weights)
+        self._back = None                 # release a superseded fill first
+        self._back = copy_buffers(weights)
         self._diff = diff
         self._target_plan = target_plan
         self._target_se = np.asarray(diff.target_slot_experts)

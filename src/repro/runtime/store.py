@@ -31,6 +31,13 @@ def store_sharding(mesh, ndim: int, ep_axis: str = "model"):
     return NamedSharding(mesh, P(None, ep_axis, *([None] * (ndim - 2))))
 
 
+@jax.jit
+def _gather_slots(experts: Dict[str, jnp.ndarray], idx: jnp.ndarray):
+    """{name: (L, E, ...)} x (L, S) expert ids -> {name: (L, S, ...)}."""
+    return {k: jax.vmap(lambda w, s: w[s])(w, idx)
+            for k, w in experts.items()}
+
+
 class ReplicaStore:
     """Slot-weight buffers + host-side bookkeeping (slot map, versions)."""
 
@@ -62,13 +69,36 @@ class ReplicaStore:
         se = stacked_slot_experts(plan_stack, ep_ranks, dup_slots)   # (L, S)
         rank_of_slot = np.arange(se.shape[1]) // n_slots
         fill = np.where(se >= 0, se, rank_of_slot[None, :] * e_loc)
-        fill_j = jnp.asarray(fill, jnp.int32)
-        weights = {k: jax.vmap(lambda w, s: w[s])(jnp.asarray(w), fill_j)
-                   for k, w in experts.items()}
-        if mesh is not None:
-            weights = {k: jax.device_put(
-                w, store_sharding(mesh, w.ndim, ep_axis))
-                for k, w in weights.items()}
+        if mesh is None:
+            weights = _gather_slots(experts, jnp.asarray(fill, jnp.int32))
+            return cls(weights, se, num_experts=num_experts,
+                       ep_ranks=ep_ranks, dup_slots=dup_slots)
+        # Built in place, shard by shard: every rank first fills each of
+        # its slots from its OWN home experts (home slots exactly; replica
+        # slots whose expert lives elsewhere provisionally), then the
+        # chunked migration step broadcasts the remote replicas in. No
+        # device ever holds more than its (L, n_slots, ...) store shard.
+        from jax.sharding import NamedSharding, PartitionSpec as P
+        from repro.runtime.diff import PlanDiff
+        from repro.runtime.migrate import make_migrate_step, migrate_all
+        blk = P(None, ep_axis)
+        experts = jax.device_put(experts, NamedSharding(mesh, blk))
+        local = np.where(fill // e_loc == rank_of_slot[None, :], fill,
+                         rank_of_slot[None, :] * e_loc)
+        build = jax.jit(jax.shard_map(
+            lambda w, idx: _gather_slots(w, idx % e_loc), mesh=mesh,
+            in_specs=(blk, blk), out_specs=blk, check_vma=False))
+        weights = build(experts, jnp.asarray(local, jnp.int32))
+        layer, slot = np.nonzero(local != fill)
+        if layer.size:
+            diff = PlanDiff(layer=layer.astype(np.int32),
+                            dst_slot=slot.astype(np.int32),
+                            src_expert=fill[layer, slot].astype(np.int32),
+                            target_slot_experts=se)
+            step = make_migrate_step(mesh, num_experts=num_experts,
+                                     ep_ranks=ep_ranks, dup_slots=dup_slots,
+                                     ep_axis=ep_axis)
+            weights = migrate_all(step, weights, experts, diff)
         return cls(weights, se, num_experts=num_experts, ep_ranks=ep_ranks,
                    dup_slots=dup_slots)
 

@@ -35,7 +35,7 @@ from repro.models.transformer import Runtime, init_cache
 from repro.obs.accuracy import PredictorAccuracyTracker
 from repro.obs.trace import NULL_TRACER
 from repro.serve.kvcache import (BlockAllocator, init_block_pool,
-                                 write_prefill_blocks)
+                                 pool_sharding, write_prefill_blocks)
 from repro.serve.metrics import (RequestTiming, ServeMetrics, imbalance,
                                  plan_rank_loads)
 from repro.serve.scheduler import (ContinuousScheduler, IterationPlan,
@@ -113,9 +113,8 @@ class _OverlapStoreMixin:
 # XLA compile counting — the no-recompile guarantee under a mesh.
 #
 # ``jitted_fn._cache_size()`` is exact on a single device, but under a mesh
-# the C++ fastpath adds one cache entry per call for freshly-minted GSPMD
-# output shardings WITHOUT recompiling anything (observed on jax 0.4.37,
-# verified against the backend-compile log). Meshed engines therefore count
+# the C++ fastpath may add cache entries for freshly-minted GSPMD output
+# shardings WITHOUT recompiling anything. Meshed engines therefore count
 # actual backend compilations through jax.monitoring instead.
 # ---------------------------------------------------------------------------
 
@@ -735,7 +734,8 @@ class ContinuousEngine(_OverlapStoreMixin):
                           use_duplication=use_dup,
                           window_override=ccfg.max_len)
 
-        self.pool = init_block_pool(cfg, ccfg.num_blocks, ccfg.block_size)
+        self.pool = init_block_pool(cfg, ccfg.num_blocks, ccfg.block_size,
+                                    mesh=mesh)
         self.allocator = BlockAllocator(ccfg.num_blocks, ccfg.block_size)
         self.scheduler = ContinuousScheduler(
             ccfg.max_slots, ccfg.prefill_len, ccfg.max_len, self.allocator,
@@ -746,7 +746,8 @@ class ContinuousEngine(_OverlapStoreMixin):
 
         self._prefill_fn = jax.jit(make_slot_prefill_step(cfg, self.rt))
         self._decode_fn = jax.jit(make_paged_decode_step(cfg, self.rt))
-        self._write_fn = jax.jit(write_prefill_blocks)
+        self._write_fn = jax.jit(write_prefill_blocks,
+                                 out_shardings=pool_sharding(cfg, mesh))
         self._temp_cache = init_cache(cfg, self.rt, 1, ccfg.prefill_len)
         self._warm = False
 
@@ -1179,6 +1180,12 @@ class ContinuousEngine(_OverlapStoreMixin):
                 self.cfg.num_layers, jax.tree.map(lambda a: np.asarray(a)[0],
                                                   plan))))
         slot_w = self._store.weights if self._store is not None else None
+        if self._migrate_fn is not None:
+            # the migration step donates its buffers, so its warmup call
+            # consumes a copy — made OUTSIDE the mesh context, where
+            # MigrationExecutor.begin copies during serving
+            from repro.runtime.migrate import copy_buffers
+            scratch = jax.block_until_ready(copy_buffers(slot_w))
         ctx = self.mesh or _nullcontext()
         with ctx:
             back_w, ready, tplan = self._overlap_args()
@@ -1187,7 +1194,7 @@ class ContinuousEngine(_OverlapStoreMixin):
                 # entry invalid) so later plan switches never compile
                 z = jnp.zeros((self.ccfg.migrate_chunk,), jnp.int32)
                 jax.block_until_ready(self._migrate_fn(
-                    self._store.weights,
+                    scratch,
                     self.params["layers"]["moe"]["experts"],
                     z, z, z, jnp.zeros((self.ccfg.migrate_chunk,), bool)))
             for pred in preds:
@@ -1273,11 +1280,7 @@ class ContinuousEngine(_OverlapStoreMixin):
         names = ("_prefill_fn", "_decode_fn", "_write_fn") + (
             ("_migrate_fn",) if self._migrate_fn is not None else ())
         for name in names:
-            fn = getattr(self, name)
-            try:
-                out[name] = fn._cache_size()
-            except AttributeError:                      # older jit wrappers
-                out[name] = -1
+            out[name] = getattr(self, name)._cache_size()
         return out
 
     def profile_phases(self, iters: int = 3, impl: Optional[str] = None,
@@ -1353,9 +1356,6 @@ class ContinuousEngine(_OverlapStoreMixin):
     def assert_no_recompiles(self):
         assert self._warm, "call warmup() first"
         now = self.compile_counts()
-        assert all(v >= 0 for v in now.values()), (
-            "jit cache introspection unavailable on this jax version — "
-            f"the no-recompile guarantee cannot be checked: {now}")
         assert now == self._compile_baseline, (
             f"recompilation after warmup: {self._compile_baseline} -> {now}")
 

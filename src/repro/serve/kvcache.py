@@ -27,13 +27,27 @@ from repro.configs.base import ModelConfig
 NULL_BLOCK = 0
 
 
+def pool_sharding(cfg: ModelConfig, mesh):
+    """The pool's fixed layout on ``mesh`` (None without one): KV heads
+    split like the attention projections, blocks whole on every shard."""
+    if mesh is None:
+        return None
+    from jax.sharding import NamedSharding
+    from repro.sharding import paged_pool_spec
+    return NamedSharding(mesh, paged_pool_spec(mesh, cfg.num_kv_heads,
+                                               stacked=True))
+
+
 def init_block_pool(cfg: ModelConfig, num_blocks: int, block_size: int,
-                    dtype=jnp.bfloat16) -> Dict[str, jnp.ndarray]:
-    """Device-side pool. Requires a uniform-stack GQA architecture (the
-    continuous engine asserts this)."""
+                    dtype=jnp.bfloat16, mesh=None) -> Dict[str, jnp.ndarray]:
+    """Device-side pool, created in place in its ``pool_sharding``.
+    Requires a uniform-stack GQA architecture (the continuous engine
+    asserts this)."""
     L, K, hd = cfg.num_layers, cfg.num_kv_heads, cfg.head_dim
     shape = (L, num_blocks, block_size, K, hd)
-    return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
+    return jax.jit(lambda: {"k": jnp.zeros(shape, dtype),
+                            "v": jnp.zeros(shape, dtype)},
+                   out_shardings=pool_sharding(cfg, mesh))()
 
 
 def write_prefill_blocks(pool: Dict[str, jnp.ndarray],

@@ -176,6 +176,27 @@ def batch_axes(mesh: Mesh):
     return tuple(n for n in names if n in ("pod", "data"))
 
 
+def batch_axes_for(mesh: Mesh, B: int):
+    """Batch axes, dropped to replication when B isn't evenly divisible
+    (e.g. long-context decode with global_batch=1)."""
+    b = batch_axes(mesh)
+    n = int(np.prod([mesh.shape[a] for a in b])) if b else 1
+    return b if b and B % n == 0 else ()
+
+
+def kv_head_axis(mesh: Mesh, num_kv_heads: int):
+    """Mesh axis the KV-head dim of caches and paged-attention operands is
+    split over: "model", like the attention projections, when it divides
+    the KV heads evenly; None (replicated) otherwise."""
+    return "model" if num_kv_heads % mesh.shape["model"] == 0 else None
+
+
+def paged_pool_spec(mesh: Mesh, num_kv_heads: int, stacked: bool) -> P:
+    """(L?, N_blocks, block, K, hd) paged KV pool: head-split, blocks whole."""
+    h = kv_head_axis(mesh, num_kv_heads)
+    return P(*(((None,) if stacked else ()) + (None, None, h, None)))
+
+
 def act_spec(mesh: Mesh, *, seq_over_model: bool = False) -> P:
     """Activation spec for (B, S, d) tensors."""
     b = batch_axes(mesh)

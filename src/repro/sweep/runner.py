@@ -62,7 +62,8 @@ def run_job(point: SweepPoint, *, smoke: bool, trace_out: str = "",
             os.pathsep + os.environ["PYTHONPATH"]
             if os.environ.get("PYTHONPATH") else ""),
         XLA_FLAGS="--xla_force_host_platform_device_count="
-                  f"{max(point.mesh.devices, 1)}")
+                  f"{max(point.mesh.devices, 1)}",
+        JAX_PLATFORMS="cpu")
     t0 = time.perf_counter()
     try:
         out = subprocess.run(cmd, capture_output=True, text=True,

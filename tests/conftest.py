@@ -5,12 +5,6 @@ or use the 8-device session started by tests that opt in explicitly."""
 import numpy as np
 import pytest
 
-try:
-    import hypothesis  # noqa: F401
-except ImportError:
-    from tests import _hypothesis_stub
-    _hypothesis_stub._install()
-
 
 @pytest.fixture(scope="session")
 def rng():

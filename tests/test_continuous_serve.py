@@ -476,3 +476,49 @@ def test_bench_serve_traces_smoke():
     summary, derived = bench_serve_traces.run(verbose=False, smoke=True)
     assert summary["completed"] > 0
     assert "completed=" in derived
+
+
+# --------------------------------------------------------------------------
+# one-rank expert parallelism: the one-chip serving configuration
+# --------------------------------------------------------------------------
+
+def test_one_by_one_mesh_engine_serves_through_ep_dispatch():
+    """The one-chip deployment: bf16 weights placed by ``init_params`` on a
+    1x1 Auto mesh, one EP rank, no replica slots. MoE runs through the EP
+    dispatch (not the dense reference) and serves the same greedy tokens
+    as the meshless engine on the same weights, with zero drops and zero
+    compiles after warmup."""
+    import dataclasses
+    from repro.launch.mesh import make_dev_mesh
+    from repro.models.transformer import init_params
+
+    cfg = _cfg()
+    # dropless capacity, as the one-chip smoke runs Mixtral
+    cfg = dataclasses.replace(cfg, moe=dataclasses.replace(
+        cfg.moe, capacity_factor=cfg.moe.num_experts / cfg.moe.top_k))
+    mesh = make_dev_mesh(1, 1, devices=jax.devices()[:1])
+    params = init_params(KEY, cfg, mesh=mesh)
+    assert {a.dtype for a in jax.tree.leaves(params)} == {jnp.dtype("bfloat16")}
+    ccfg = ContinuousConfig(max_slots=4, prefill_len=32, block_size=8,
+                            max_len=64, strategy="none", dup_slots=0)
+    rng = np.random.default_rng(0)
+    prompts = [rng.integers(0, cfg.vocab_size, int(n)).tolist()
+               for n in rng.integers(5, 33, 6)]
+    served = {}
+    for name, m in (("ep", mesh), ("dense", None)):
+        eng = ContinuousEngine(cfg, params, ccfg, mesh=m, ep_ranks=1)
+        assert eng.rt.ep == (m is not None)
+        eng.warmup()
+        reqs = [ServeRequest(rid=i, tokens=p, max_new_tokens=6)
+                for i, p in enumerate(prompts)]
+        now = 0.0
+        for r in reqs:
+            eng.submit(r)
+        while eng.has_work():
+            eng.step(now)
+            now += 1.0
+        eng.assert_no_recompiles()
+        assert all(len(r.generated) == 6 for r in reqs)
+        assert eng.metrics.resched["dropped_tokens"] == 0
+        served[name] = [r.generated for r in reqs]
+    assert served["ep"] == served["dense"]

@@ -130,7 +130,8 @@ def test_ep_forward_sort_matches_onehot_multidevice():
         from repro.models.transformer import Runtime, forward, init_model
 
         base = get_config("mixtral-8x7b").reduced()
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2, 4), ("data", "model"))
         rt = Runtime(mesh=mesh, ep=True, ep_ranks=4)
         out = {}
         for top_k, cap_f, predicted in itertools.product(
@@ -187,7 +188,8 @@ def test_ep_decode_sort_matches_onehot_multidevice():
         from repro.train.steps import make_decode_step
 
         base = get_config("mixtral-8x7b").reduced()
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2, 4), ("data", "model"))
         rt = Runtime(mesh=mesh, ep=True, ep_ranks=4)
         B = 4
         tok = jnp.ones((B, 1), jnp.int32)

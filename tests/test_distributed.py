@@ -52,7 +52,8 @@ def test_ep_dispatch_matches_dense_reference():
                                               (4, 32), 0, cfg.vocab_size)}
         dense_logits, _, _ = forward(params, cfg, batch, Runtime(),
                                      mode="train")
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2, 4), ("data", "model"))
         rt = Runtime(mesh=mesh, ep=True, ep_ranks=4)
         ep_logits, _, stats = jax.jit(
             lambda p, b: forward(p, cfg, b, rt, mode="train"))(params, batch)
@@ -70,7 +71,9 @@ def test_duplication_improves_measured_balance():
         from repro.serve import ServeEngine, ServeConfig
         from repro.data.synthetic import token_batches
 
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+
+        mesh = make_mesh((2, 4), ("data", "model"))
         cfg = get_config("mixtral-8x7b").reduced()
         params = init_model(jax.random.PRNGKey(0), cfg)
         out = {}
@@ -104,7 +107,8 @@ def test_t2e_predicted_dispatch_correctness():
         B, S = 4, 32
         batch = {"tokens": jax.random.randint(jax.random.PRNGKey(1),
                                               (B, S), 0, cfg.vocab_size)}
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2, 4), ("data", "model"))
         rt = Runtime(mesh=mesh, ep=True, ep_ranks=4)
         ref_logits, _, _ = jax.jit(
             lambda p, b: forward(p, cfg, b, rt, mode="train"))(params, batch)
@@ -134,7 +138,9 @@ def test_param_specs_shard_and_gather_consistency():
         batch = {"tokens": jnp.zeros((4, 16), jnp.int32)}
         ref, _, _ = forward(params, cfg, batch, Runtime(), mode="train")
 
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+
+        mesh = make_mesh((2, 4), ("data", "model"))
         specs = param_specs(params, fsdp_axes=("data",), fsdp_size=2,
                             mesh=mesh)
         sharded = jax.device_put(params, make_shardings(mesh, specs))
@@ -170,7 +176,9 @@ def test_dev_mesh_decode_moe():
         _, dl, _, _ = make_decode_step(cfg, Runtime())(
             params, tok, dense_cache, 5)
 
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+
+        mesh = make_mesh((2, 4), ("data", "model"))
         rt = Runtime(mesh=mesh, ep=True, ep_ranks=4)
         ep_cache = init_cache(cfg, rt, B, 32)
         with mesh:
@@ -202,7 +210,8 @@ def test_expert_tp_decode_matches_dense():
         tok = jnp.ones((B, 1), jnp.int32)
         _, dl, _, _ = make_decode_step(cfg, Runtime())(
             params, tok, init_cache(cfg, Runtime(), B, 32), 5)
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2, 4), ("data", "model"))
         rt = Runtime(mesh=mesh, ep=True, ep_ranks=4, decode_expert_tp=True)
         cache = init_cache(cfg, rt, B, 32)
         with mesh:
@@ -237,7 +246,8 @@ def test_in_graph_replan_balances():
         from repro.data.synthetic import token_batches
 
         np.random.seed(0)                       # routing inputs: one stream
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2, 4), ("data", "model"))
         cfg = get_config("mixtral-8x7b").reduced()
         params = init_model(jax.random.PRNGKey(0), cfg)
         out = {}

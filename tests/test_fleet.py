@@ -543,7 +543,9 @@ def test_fleet_meshed_smoke_arbiter_move_no_recompile():
         from repro.models.transformer import init_model
         from repro.serve import ContinuousConfig, ServeRequest
 
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+
+        mesh = make_mesh((2, 4), ("data", "model"))
         cfg = get_config("mixtral-8x7b").reduced()
         params = init_model(jax.random.PRNGKey(0), cfg)
         ccfg = ContinuousConfig(max_slots=2, prefill_len=16, block_size=8,

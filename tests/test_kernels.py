@@ -29,7 +29,8 @@ def test_moe_gemm_matches_ref(S, T, d, F, dtype, activation):
     wg = (jax.random.normal(ks[1], (S, d, F), jnp.float32) * 0.05).astype(dtype)
     wu = (jax.random.normal(ks[2], (S, d, F), jnp.float32) * 0.05).astype(dtype)
     wd = (jax.random.normal(ks[3], (S, F, d), jnp.float32) * 0.05).astype(dtype)
-    out = moe_gemm(x, wg, wu, wd, activation=activation)
+    out = moe_gemm(x, wg, wu, wd, activation=activation,
+                   interpret=ops._interpret())
     ref = moe_gemm_ref(x, wg, wu, wd, activation)
     tol = 1e-5 if dtype == jnp.float32 else 3e-2
     np.testing.assert_allclose(np.asarray(out, np.float32),
@@ -54,7 +55,7 @@ def test_moe_gemm_ops_wrapper_matches_dispatch_grouped_ffn():
                                  (1024, 256)])
 def test_histogram_matches_ref(N, E):
     idx = jax.random.randint(KEY, (N,), 0, E)
-    out = histogram(idx, E)
+    out = histogram(idx, E, interpret=ops._interpret())
     ref = histogram_ref(idx, E)
     assert (np.asarray(out) == np.asarray(ref)).all()
     assert int(out.sum()) == N
@@ -76,7 +77,7 @@ def test_rg_lru_matches_ref(B, S, D):
     a = jax.random.uniform(ks[0], (B, S, D), jnp.float32, 0.5, 0.99)
     b = jax.random.normal(ks[1], (B, S, D), jnp.float32) * 0.1
     h0 = jax.random.normal(ks[2], (B, D), jnp.float32)
-    out, hl = rg_lru_scan(a, b, h0)
+    out, hl = rg_lru_scan(a, b, h0, interpret=ops._interpret())
     ro, rh = rg_lru_ref(a, b, h0)
     np.testing.assert_allclose(out, ro, atol=1e-5, rtol=1e-5)
     np.testing.assert_allclose(hl, rh, atol=1e-5, rtol=1e-5)
@@ -102,7 +103,8 @@ def test_rg_lru_matches_griffin_associative_scan():
     a = jnp.exp(log_a)
     b = jnp.sqrt(jnp.maximum(1 - jnp.exp(2 * log_a), 1e-12)) * (i * x.astype(f32))
     b = b.at[:, 0].add(a[:, 0] * h0)
-    out, hl = rg_lru_scan(a, b, jnp.zeros_like(h0))
+    out, hl = rg_lru_scan(a, b, jnp.zeros_like(h0),
+                          interpret=ops._interpret())
     np.testing.assert_allclose(np.asarray(out), np.asarray(y_model, np.float32),
                                atol=1e-4, rtol=1e-4)
     np.testing.assert_allclose(np.asarray(hl), np.asarray(h_model), atol=1e-4,

@@ -296,7 +296,8 @@ def test_serve_engine_applies_store_hbm_budget():
         cfg, moe=dataclasses.replace(cfg.moe, store_hbm_budget_gb=budget_gb))
     # the clamp requires store mode, and a store requires a mesh (the
     # engine is only constructed, never stepped, so 1x1 is fine here)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((1, 1), ("data", "model"))
     eng = ServeEngine(clamped, params, ServeConfig(dup_slots=4),
                       mesh=mesh, ep_ranks=4)
     assert eng.moe_cfg.duplication_slots == 1
@@ -354,7 +355,8 @@ def test_overlapped_forward_bitexact_vs_gather_midstream():
         cfg = dataclasses.replace(base, moe=dataclasses.replace(
             base.moe, duplication_slots=2))
         E = cfg.moe.num_experts
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2, 4), ("data", "model"))
         rt = Runtime(mesh=mesh, ep=True, ep_ranks=4, use_duplication=True)
         params = init_model(jax.random.PRNGKey(0), cfg)
         experts = params["layers"]["moe"]["experts"]
@@ -436,7 +438,9 @@ def test_serve_engine_generate_tokens_equal_overlap_on_off():
         from repro.models.transformer import init_model
         from repro.serve import ServeConfig, ServeEngine
 
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+
+        mesh = make_mesh((2, 4), ("data", "model"))
         cfg = get_config("mixtral-8x7b").reduced()
         params = init_model(jax.random.PRNGKey(0), cfg)
         outs = {}
@@ -475,7 +479,9 @@ def test_meshed_engine_prefetch_overlap_no_recompiles():
         from repro.serve import ContinuousConfig, ContinuousEngine
         from repro.serve.scheduler import ServeRequest
 
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+
+        mesh = make_mesh((2, 4), ("data", "model"))
         cfg = get_config("mixtral-8x7b").reduced()
         params = init_model(jax.random.PRNGKey(0), cfg)
         ccfg = ContinuousConfig(max_slots=4, prefill_len=32, block_size=16,

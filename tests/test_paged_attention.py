@@ -207,7 +207,9 @@ def test_meshed_engine_fused_decode_no_recompiles():
         from repro.serve import ContinuousConfig, ContinuousEngine
         from repro.serve.scheduler import ServeRequest
 
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+
+        mesh = make_mesh((2, 4), ("data", "model"))
         cfg = dataclasses.replace(get_config("mixtral-8x7b").reduced(),
                                   paged_attn_impl="fused")
         params = init_model(jax.random.PRNGKey(0), cfg)

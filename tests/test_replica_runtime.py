@@ -242,7 +242,8 @@ def test_store_forward_matches_gather_multidevice():
                                    stacked_slot_experts)
 
         base = get_config("mixtral-8x7b").reduced()
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2, 4), ("data", "model"))
         rt = Runtime(mesh=mesh, ep=True, ep_ranks=4, use_duplication=True)
         E = base.moe.num_experts
         out = {}
@@ -338,7 +339,8 @@ def test_identity_plan_skips_pool_gather_but_matches():
         base = get_config("mixtral-8x7b").reduced()
         cfg = dataclasses.replace(base, moe=dataclasses.replace(
             base.moe, duplication_slots=1, capacity_factor=8.0))
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2, 4), ("data", "model"))
         rt = Runtime(mesh=mesh, ep=True, ep_ranks=4, use_duplication=True)
         params = init_model(jax.random.PRNGKey(0), cfg)
         E = cfg.moe.num_experts
@@ -381,7 +383,9 @@ def test_continuous_engine_store_migrates_without_recompiles():
         from repro.serve import ContinuousConfig, ContinuousEngine
         from repro.serve.scheduler import ServeRequest
 
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+
+        mesh = make_mesh((2, 4), ("data", "model"))
         cfg = get_config("mixtral-8x7b").reduced()
         params = init_model(jax.random.PRNGKey(0), cfg)
         ccfg = ContinuousConfig(max_slots=4, prefill_len=32, block_size=16,

@@ -172,7 +172,8 @@ def test_ep_forward_with_resched_sort_matches_onehot_multidevice():
         from repro.schedule import make_scheduler
 
         base = get_config("mixtral-8x7b").reduced()
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2, 4), ("data", "model"))
         rt = Runtime(mesh=mesh, ep=True, ep_ranks=4)
         m = base.moe
         B, S = 4, 32
@@ -246,7 +247,8 @@ def test_engine_reschedule_zero_drops_no_recompiles_multidevice():
         cfg = dataclasses.replace(base, moe=dataclasses.replace(
             base.moe, capacity_factor=0.5, duplication_slots=1))
         params = init_model(jax.random.PRNGKey(0), cfg)
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2, 4), ("data", "model"))
         out = {}
         for lever in ("duplicate", "reschedule"):
             ccfg = ContinuousConfig(max_slots=4, prefill_len=64,

@@ -2,6 +2,8 @@
 (single device)."""
 
 
+import os
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -92,6 +94,25 @@ def test_train_driver_loss_goes_down():
     rc = main(["--arch", "qwen1.5-0.5b", "--reduced", "--steps", "20",
                "--batch", "4", "--seq", "32", "--log-every", "50"])
     assert rc == 0
+
+
+def test_compile_cache_location(monkeypatch, tmp_path):
+    """JAX_COMPILATION_CACHE_DIR wins untouched; unset, the cache goes to
+    the fixed <repo>/.jax_cache."""
+    from repro.launch import compile_cache
+    prev = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert compile_cache.enable_compile_cache() == str(tmp_path)
+    assert jax.config.jax_compilation_cache_dir == prev
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    try:
+        got = compile_cache.enable_compile_cache()
+        assert got == os.path.join(compile_cache.REPO_ROOT, ".jax_cache")
+        assert os.path.isfile(os.path.join(compile_cache.REPO_ROOT,
+                                           "chip_smoke.py"))
+        assert jax.config.jax_compilation_cache_dir == got
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev)
 
 
 def test_schedules():
