@@ -1,0 +1,19 @@
+"""Model FLOP utilisation of the whole serving step: the top-k model
+FLOPs of the traced window (each prompt's real tokens, each decoded
+token, attention at its actual context; no bucket or capacity padding)
+over window seconds x chips x peak bf16 FLOP/s."""
+
+import counts
+
+
+def read(ctx):
+    steps = ctx.steps
+    if not steps:
+        return None
+    D = ctx.D
+    flops = sum(counts.prefill_flops(D, p) for s in steps for p in s.prefills)
+    flops += sum(counts.decode_flops(D, c) for s in steps for c in s.decodes)
+    window = steps[-1].t1 - steps[0].t0
+    if not flops or window <= 0:
+        return None
+    return 100.0 * flops / (window * ctx.chips * ctx.peak["bf16_flops_per_s"])
