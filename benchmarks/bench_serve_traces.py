@@ -91,10 +91,11 @@ MESHED_SLO_MS = 2500.0
 # must stay under 1% of a meshed serving step.
 TRACER_OFF_BUDGET_FRAC = 0.01
 
-# Conservative count of tracer call sites one engine step can hit (step +
-# admission + 2 prefills + decode + observe spans, migration tick span +
-# begin/commit instants, plan/gps instants, boundary counters).
-_TRACER_OPS_PER_STEP = 24
+# Conservative count of tracer call sites one engine step can hit (step,
+# plan, admission, 2 x (prefill + prefill.sync), decode + its 4 parts,
+# observe and record spans, migration tick span + begin/commit instants,
+# plan/gps instants, boundary counters).
+_TRACER_OPS_PER_STEP = 32
 
 _MESHED_SUB = """
 import os

@@ -174,15 +174,19 @@ def gqa_attention(params, cfg: ModelConfig, x, positions, *, window=0,
 
 def gqa_prefill(params, cfg: ModelConfig, x, positions, cache, *, window=0):
     """Prefill: run attention AND write k/v into the cache (from position 0)."""
-    q, k, v = gqa_project(params, cfg, x, positions)
-    out = chunked_attention(q, k, v, causal=True, window=window)
+    with jax.named_scope("attn.qkv"):
+        q, k, v = gqa_project(params, cfg, x, positions)
+    with jax.named_scope("attn.causal"):
+        out = chunked_attention(q, k, v, causal=True, window=window)
     B, S = x.shape[:2]
     cache = dict(cache)
-    cache["k"] = jax.lax.dynamic_update_slice(
-        cache["k"], k.astype(cache["k"].dtype), (0, 0, 0, 0))
-    cache["v"] = jax.lax.dynamic_update_slice(
-        cache["v"], v.astype(cache["v"].dtype), (0, 0, 0, 0))
-    return dense(params["wo"], out.reshape(B, S, -1)), cache
+    with jax.named_scope("attn.kv_write"):
+        cache["k"] = jax.lax.dynamic_update_slice(
+            cache["k"], k.astype(cache["k"].dtype), (0, 0, 0, 0))
+        cache["v"] = jax.lax.dynamic_update_slice(
+            cache["v"], v.astype(cache["v"].dtype), (0, 0, 0, 0))
+    with jax.named_scope("attn.out"):
+        return dense(params["wo"], out.reshape(B, S, -1)), cache
 
 
 def gqa_decode(params, cfg: ModelConfig, x, cache, cache_len, *, window=0):
@@ -253,34 +257,37 @@ def gqa_decode_paged(params, cfg: ModelConfig, x, pool, block_tables, lengths,
     N, bs, K, hd = pool["k"].shape
     lengths = jnp.asarray(lengths, jnp.int32)
     positions = lengths[:, None]                                  # (B, 1)
-    q, k, v = gqa_project(params, cfg, x, positions)
-    b_idx = jnp.arange(B)
-    blk = block_tables[b_idx, positions[:, 0] // bs]              # (B,)
-    off = positions[:, 0] % bs                                    # (B,)
-    # slots own disjoint blocks, so cross-slot collisions only happen on the
-    # null block; inactive slots (lengths == 0 after release) keep the old
-    # value — their table rows all point at the null block, which must stay
-    # clean for every other slot's masked reads
-    active = (lengths > 0)[:, None, None]                         # (B, 1, 1)
-    k_pool = pool["k"].at[blk, off].set(
-        jnp.where(active, k[:, 0].astype(pool["k"].dtype),
-                  pool["k"][blk, off]))
-    v_pool = pool["v"].at[blk, off].set(
-        jnp.where(active, v[:, 0].astype(pool["v"].dtype),
-                  pool["v"][blk, off]))
+    with jax.named_scope("attn.qkv"):
+        q, k, v = gqa_project(params, cfg, x, positions)
+    with jax.named_scope("attn.kv_write"):
+        b_idx = jnp.arange(B)
+        blk = block_tables[b_idx, positions[:, 0] // bs]          # (B,)
+        off = positions[:, 0] % bs                                # (B,)
+        # slots own disjoint blocks, so cross-slot collisions only happen
+        # on the null block; inactive slots (lengths == 0 after release)
+        # keep the old value — their table rows all point at the null
+        # block, which must stay clean for every other slot's masked reads
+        active = (lengths > 0)[:, None, None]                     # (B, 1, 1)
+        k_pool = pool["k"].at[blk, off].set(
+            jnp.where(active, k[:, 0].astype(pool["k"].dtype),
+                      pool["k"][blk, off]))
+        v_pool = pool["v"].at[blk, off].set(
+            jnp.where(active, v[:, 0].astype(pool["v"].dtype),
+                      pool["v"][blk, off]))
     G = q.shape[2] // K
     qg = q.reshape(B, K, G, hd)
     impl = getattr(cfg, "paged_attn_impl", "fused")
-    if impl == "fused":
-        out = _kernel_ops.paged_decode_attention(
-            qg, k_pool, v_pool, block_tables, lengths, window=window,
-            mesh=mesh)
-    else:
-        # gather each slot's view: (B, M, bs, K, hd) -> (B, M*bs, K, hd)
-        k_view = k_pool[block_tables].reshape(B, -1, K, hd)
-        v_view = v_pool[block_tables].reshape(B, -1, K, hd)
-        out = _kernel_ref.paged_decode_ref(qg, k_view, v_view, lengths,
-                                           window=window, block_size=bs)
+    with jax.named_scope("attn.paged"):
+        if impl == "fused":
+            out = _kernel_ops.paged_decode_attention(
+                qg, k_pool, v_pool, block_tables, lengths, window=window,
+                mesh=mesh)
+        else:
+            # gather each slot's view: (B, M, bs, K, hd) -> (B, M*bs, K, hd)
+            k_view = k_pool[block_tables].reshape(B, -1, K, hd)
+            v_view = v_pool[block_tables].reshape(B, -1, K, hd)
+            out = _kernel_ref.paged_decode_ref(qg, k_view, v_view, lengths,
+                                               window=window, block_size=bs)
     if mesh is not None:
         # pin the pool layout so every step sees the same input sharding
         # (each distinct layout would be its own compiled program)
@@ -290,7 +297,8 @@ def gqa_decode_paged(params, cfg: ModelConfig, x, pool, block_tables, lengths,
         k_pool = jax.lax.with_sharding_constraint(k_pool, sh)
         v_pool = jax.lax.with_sharding_constraint(v_pool, sh)
     pool = {**pool, "k": k_pool, "v": v_pool}
-    return dense(params["wo"], out.reshape(B, 1, -1)), pool
+    with jax.named_scope("attn.out"):
+        return dense(params["wo"], out.reshape(B, 1, -1)), pool
 
 
 def init_gqa_cache(cfg: ModelConfig, batch: int, max_len: int, dtype=jnp.bfloat16,

@@ -334,7 +334,8 @@ def moe_apply(layer_p, cfg: ModelConfig, x, rt: Runtime, plan_l,
     def inner(x_blk, router_w, experts_w, plan, pred, w_blk, slot_blk, quota,
               mig):
         t = x_blk.reshape(-1, x_blk.shape[-1])
-        router_out = route(router_w, moe, t, impl=router_impl)
+        with jax.named_scope("moe.route"):
+            router_out = route(router_w, moe, t, impl=router_impl)
 
         def dispatch(plan, slots):
             return dispatch_fn(
@@ -702,15 +703,20 @@ def forward(params, cfg: ModelConfig, batch, rt: Runtime, *, mode: str,
         def body(h, xs):
             (layer_p, cache_l, plan_l, pred_l, slot_l, back_l, ready_l,
              tplan_l, resched_l) = xs
-            h = constrain_acts(h, rt, seq_shard)
-            h, new_c, st = _attn_layer(
-                layer_p, cfg, h, positions, rt, cache=cache_l,
-                cache_len=cache_len, mode=mode, enc_out=enc_out,
-                plan_l=plan_l, predicted_l=pred_l,
-                block_tables=block_tables, token_weight=token_weight,
-                slot_w_l=slot_l, resched_l=resched_l,
-                migration_l=(ready_l, tplan_l, back_l) if overlap else None)
-            return constrain_acts(h, rt, seq_shard), (new_c, st)
+            # the body's own ops (norms, residual adds) fall under
+            # "layer.body"; ops under "layers" and outside it are the scan
+            # slicing its xs and writing its ys (the stacked caches)
+            with jax.named_scope("layer.body"):
+                h = constrain_acts(h, rt, seq_shard)
+                h, new_c, st = _attn_layer(
+                    layer_p, cfg, h, positions, rt, cache=cache_l,
+                    cache_len=cache_len, mode=mode, enc_out=enc_out,
+                    plan_l=plan_l, predicted_l=pred_l,
+                    block_tables=block_tables, token_weight=token_weight,
+                    slot_w_l=slot_l, resched_l=resched_l,
+                    migration_l=((ready_l, tplan_l, back_l) if overlap
+                                 else None))
+                return constrain_acts(h, rt, seq_shard), (new_c, st)
 
         xs = (params["layers"], cache,
               plan if plan is not None else _none_stack(L),
@@ -720,7 +726,8 @@ def forward(params, cfg: ModelConfig, batch, rt: Runtime, *, mode: str,
               slot_ready if overlap else _none_stack(L),
               target_plan if overlap else _none_stack(L),
               resched if resched is not None else _none_stack(L))
-        x, (new_cache, layer_stats) = jax.lax.scan(body, x, xs)
+        with jax.named_scope("layers"):
+            x, (new_cache, layer_stats) = jax.lax.scan(body, x, xs)
         if cfg.is_moe:
             counts, slots, aux, z, dropped, overflow = layer_stats
             stats = {"expert_counts": counts, "slot_counts": slots,
@@ -730,17 +737,17 @@ def forward(params, cfg: ModelConfig, batch, rt: Runtime, *, mode: str,
         if mode == "train":
             new_cache = None
 
-    if mode == "prefill":
-        if last_pos is not None:
-            B = x.shape[0]
-            x_last = x[jnp.arange(B), jnp.asarray(last_pos, jnp.int32)][:, None]
-            logits = _logits(params, cfg, x_last)
+    with jax.named_scope("lm_head"):
+        if mode == "prefill":
+            if last_pos is not None:
+                B = x.shape[0]
+                x_last = x[jnp.arange(B),
+                           jnp.asarray(last_pos, jnp.int32)][:, None]
+                logits = _logits(params, cfg, x_last)
+            else:
+                logits = _logits(params, cfg, x[:, -1:])
         else:
-            logits = _logits(params, cfg, x[:, -1:])
-    elif mode == "decode":
-        logits = _logits(params, cfg, x)
-    else:
-        logits = _logits(params, cfg, x)
+            logits = _logits(params, cfg, x)
     return logits, new_cache, stats
 
 

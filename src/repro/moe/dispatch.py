@@ -273,9 +273,10 @@ def _dispatch_round(x, gslot, valid, *, num_slots: int, ranks: int, cap: int,
     S = ranks * num_slots
     token_of = jnp.arange(N, dtype=jnp.int32) // K
 
-    send, in_cap, dest, slot_counts, dropped = _PACKERS[impl](
-        x, token_of, gslot, valid, num_classes=S, cap=cap,
-        use_kernel=use_kernel)
+    with jax.named_scope("moe.pack"):
+        send, in_cap, dest, slot_counts, dropped = _PACKERS[impl](
+            x, token_of, gslot, valid, num_classes=S, cap=cap,
+            use_kernel=use_kernel)
     send = send.reshape(ranks, num_slots * cap, d)
     recv = jax.lax.all_to_all(send, axis_name, split_axis=0, concat_axis=0,
                               tiled=False)
@@ -283,18 +284,20 @@ def _dispatch_round(x, gslot, valid, *, num_slots: int, ranks: int, cap: int,
     recv = recv.reshape(ranks, num_slots, cap, d).transpose(1, 0, 2, 3) \
                .reshape(num_slots, ranks * cap, d)
 
-    if use_kernel:
-        from repro.kernels import ops as kernel_ops
-        y_slots = kernel_ops.moe_gemm(recv, slot_w, activation)
-    else:
-        y_slots = grouped_ffn(slot_w, recv, activation)
+    with jax.named_scope("moe.expert_ffn"):
+        if use_kernel:
+            from repro.kernels import ops as kernel_ops
+            y_slots = kernel_ops.moe_gemm(recv, slot_w, activation)
+        else:
+            y_slots = grouped_ffn(slot_w, recv, activation)
 
     y_back = y_slots.reshape(num_slots, ranks, cap, d).transpose(1, 0, 2, 3) \
                     .reshape(ranks, num_slots * cap, d)
     y_recv = jax.lax.all_to_all(y_back, axis_name, split_axis=0, concat_axis=0,
                                 tiled=False).reshape(S * cap, d)
-    y_flat = jnp.where(in_cap[:, None],
-                       y_recv[jnp.minimum(dest, S * cap - 1)], 0.0)
+    with jax.named_scope("moe.combine"):
+        y_flat = jnp.where(in_cap[:, None],
+                           y_recv[jnp.minimum(dest, S * cap - 1)], 0.0)
     return y_flat, slot_counts, dropped, in_cap
 
 
@@ -343,11 +346,12 @@ def ep_moe_ffn(
     impl = moe.dispatch_impl
     overflow = jnp.zeros((), jnp.int32)
     if predicted_idx is None:
-        if resched_quota is None:
-            gslot = choose_replica(plan, flat(true_idx), flat(salt))
-        else:
-            gslot = choose_replica_quota(plan, resched_quota,
-                                         flat(true_idx), flat(salt))
+        with jax.named_scope("moe.pack"):
+            if resched_quota is None:
+                gslot = choose_replica(plan, flat(true_idx), flat(salt))
+            else:
+                gslot = choose_replica_quota(plan, resched_quota,
+                                             flat(true_idx), flat(salt))
         valid = jnp.ones((T * K,), bool)
         y_flat, slot_counts, dropped, in_cap = _dispatch_round(
             x, gslot, valid, num_slots=n_slots, ranks=ep_ranks, cap=cap,
@@ -393,7 +397,8 @@ def ep_moe_ffn(
         slot_counts = slot_counts + slot_counts2
         dropped = dropped1 + dropped2   # slight overcount: r1 drops of mispredicted pairs
 
-    y = (y_flat.reshape(T, K, d) * gates[..., None]).sum(axis=1)
+    with jax.named_scope("moe.combine"):
+        y = (y_flat.reshape(T, K, d) * gates[..., None]).sum(axis=1)
 
     counts = jnp.zeros((E,), jnp.float32).at[flat(true_idx)].add(1.0)
     stats = MoEStats(
@@ -453,29 +458,32 @@ def ep_moe_ffn_replicated(
     flat = lambda a: a.reshape(-1)
     salt = (jnp.arange(T, dtype=jnp.int32)[:, None] + jnp.arange(K)[None, :])
     expert_flat = flat(router_out.expert_idx)
-    if resched_quota is None:
-        gslot = choose_replica(plan, expert_flat, flat(salt))
-    else:
-        gslot = choose_replica_quota(plan, resched_quota, expert_flat,
-                                     flat(salt))
-    mine = (gslot // n_slots) == rank
     token_of = jnp.arange(T * K, dtype=jnp.int32) // K
 
     def _local_ffn(send):
-        xs = send.reshape(n_slots, cap, d)
-        if use_kernel:
-            from repro.kernels import ops as kernel_ops
-            ys = kernel_ops.moe_gemm(xs, slot_w, activation)
-        else:
-            ys = grouped_ffn(slot_w, xs, activation)
-        return ys.reshape(n_slots * cap, d)
+        with jax.named_scope("moe.expert_ffn"):
+            xs = send.reshape(n_slots, cap, d)
+            if use_kernel:
+                from repro.kernels import ops as kernel_ops
+                ys = kernel_ops.moe_gemm(xs, slot_w, activation)
+            else:
+                ys = grouped_ffn(slot_w, xs, activation)
+            return ys.reshape(n_slots * cap, d)
 
-    send, in_cap, dest, _, dropped = _PACKERS[moe.dispatch_impl](
-        x, token_of, gslot % n_slots, mine, num_classes=n_slots, cap=cap,
-        use_kernel=use_kernel)
+    with jax.named_scope("moe.pack"):
+        if resched_quota is None:
+            gslot = choose_replica(plan, expert_flat, flat(salt))
+        else:
+            gslot = choose_replica_quota(plan, resched_quota, expert_flat,
+                                         flat(salt))
+        mine = (gslot // n_slots) == rank
+        send, in_cap, dest, _, dropped = _PACKERS[moe.dispatch_impl](
+            x, token_of, gslot % n_slots, mine, num_classes=n_slots, cap=cap,
+            use_kernel=use_kernel)
     ys = _local_ffn(send)
-    y_flat = jnp.where(in_cap[:, None], ys[jnp.minimum(dest, n_slots * cap - 1)],
-                       0.0)
+    with jax.named_scope("moe.combine"):
+        y_flat = jnp.where(in_cap[:, None],
+                           ys[jnp.minimum(dest, n_slots * cap - 1)], 0.0)
     overflow = jnp.zeros((), jnp.int32)
     if resched_quota is not None:
         # Rescue round: every rank recomputes the GLOBAL first-come
@@ -501,10 +509,11 @@ def ep_moe_ffn_replicated(
     # expert took the pair — duplication never changes the tokens served.
     # tp_axis ranks hold d_ff shards: their outputs are PARTIAL sums over
     # f; one psum over (tp, ep) both combines f-partials and slot results.
-    y_flat = jax.lax.psum(y_flat, tuple(tp_axis) + (axis_name,) if tp_axis
-                          else axis_name)
-    gates = router_out.gates.astype(x.dtype)
-    y = (y_flat.reshape(T, K, d) * gates[..., None]).sum(axis=1)
+    with jax.named_scope("moe.combine"):
+        y_flat = jax.lax.psum(y_flat, tuple(tp_axis) + (axis_name,)
+                              if tp_axis else axis_name)
+        gates = router_out.gates.astype(x.dtype)
+        y = (y_flat.reshape(T, K, d) * gates[..., None]).sum(axis=1)
 
     counts = jnp.zeros((E,), jnp.float32).at[flat(router_out.expert_idx)].add(1.0)
     slot_counts = jnp.zeros((S,), jnp.int32).at[

@@ -16,7 +16,11 @@ flat metric floats. This tracer turns them into an inspectable timeline:
     "dispatch-profile") that render as separate Perfetto rows;
   * a disabled mode whose per-call cost is one attribute check — the
     engines are instrumented unconditionally, so tracer-off overhead on
-    the serving step must stay <1% (asserted by the bench gate).
+    the serving step must stay <1% (asserted by the bench gate);
+  * a second sink: an enabled tracer also opens a
+    ``jax.profiler.TraceAnnotation`` of the same name (entry args as its
+    metadata) around each span and instant, so under the JAX profiler
+    they land in its host plane, on the clock of the device events.
 
 Export follows the Chrome trace-event JSON-object format (the one
 Perfetto and chrome://tracing load directly): complete ("X") events with
@@ -31,6 +35,8 @@ import json
 import threading
 import time
 from typing import Any, Dict, List, Optional, Sequence, Tuple
+
+from jax.profiler import TraceAnnotation
 
 # event tuples: (ph, name, cat, ts_ns, dur_ns, tid, args)
 _PH_COMPLETE = "X"
@@ -59,8 +65,10 @@ _NULL_SPAN = _NullSpan()
 
 
 class _Span:
-    """Open span: records a complete ("X") event on exit."""
-    __slots__ = ("tracer", "name", "cat", "tid", "args", "t0")
+    """Open span: records a complete ("X") event on exit. The profiler
+    annotation opens before ``t0`` and closes after ``t1``, so the
+    recorded duration leaves out the cost of both sinks."""
+    __slots__ = ("tracer", "name", "cat", "tid", "args", "t0", "ann")
 
     def __init__(self, tracer: "SpanTracer", name: str, cat: str,
                  tid: int, args: Optional[dict]):
@@ -70,8 +78,11 @@ class _Span:
         self.tid = tid
         self.args = args
         self.t0 = 0
+        self.ann = None
 
     def __enter__(self):
+        self.ann = TraceAnnotation(self.name, **(self.args or {}))
+        self.ann.__enter__()
         self.t0 = time.perf_counter_ns()
         return self
 
@@ -84,6 +95,7 @@ class _Span:
         t1 = time.perf_counter_ns()
         self.tracer._append((_PH_COMPLETE, self.name, self.cat, self.t0,
                              t1 - self.t0, self.tid, self.args))
+        self.ann.__exit__(None, None, None)
         return False
 
 
@@ -139,8 +151,9 @@ class SpanTracer:
                 args: Optional[dict] = None) -> None:
         if not self.enabled:
             return
-        self._append((_PH_INSTANT, name, cat, time.perf_counter_ns(), 0,
-                      self._tid(track), args))
+        with TraceAnnotation(name, **(args or {})):
+            self._append((_PH_INSTANT, name, cat, time.perf_counter_ns(), 0,
+                          self._tid(track), args))
 
     def counter(self, name: str, value: float, cat: str = "serve",
                 track: Optional[str] = None,

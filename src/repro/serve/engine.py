@@ -1283,6 +1283,45 @@ class ContinuousEngine(_OverlapStoreMixin):
             out[name] = getattr(self, name)._cache_size()
         return out
 
+    def program_texts(self) -> Dict[str, str]:
+        """Optimized HLO text of the step programs, keyed by their jit
+        names (``prefill_step``, ``decode_step``, ``write_prefill_blocks``),
+        at the shapes ``warmup`` compiled and in the variant ``step`` runs
+        now (this plan, reschedule quota and overlap arguments, no token
+        predictions). Each program is lowered and compiled again, which the
+        persistent compile cache serves once warm; its instruction names
+        are those of the profiler's device events, and each instruction's
+        ``op_name`` metadata carries the model's named scopes. Take the
+        texts after a measured window, not before it: on a TPU v5e the
+        window that followed this call served 8.6% fewer steps, its
+        device idle inside the decode's token pull."""
+        assert self._warm, "call warmup() first"
+        ccfg = self.ccfg
+        S, B = ccfg.prefill_len, ccfg.max_slots
+        plan = self._current_plan()
+        resched = (self._resched_stack
+                   if self.lever in ("reschedule", "both") else None)
+        slot_w = self._store.weights if self._store is not None else None
+        t = self.scheduler.tables
+        with self.mesh or _nullcontext():
+            back_w, ready, tplan = self._overlap_args()
+            prefill = self._prefill_fn.lower(
+                self.params, {"tokens": jnp.zeros((1, S), jnp.int32)},
+                self._temp_cache, plan, None, jnp.zeros((1,), jnp.int32),
+                jnp.zeros((1, S), jnp.float32), slot_w, back_w, ready, tplan,
+                resched).compile()
+            write = self._write_fn.lower(
+                self.pool, prefill.out_info[2],
+                jnp.zeros((S // ccfg.block_size,), jnp.int32)).compile()
+            decode = self._decode_fn.lower(
+                self.params, jnp.zeros((B, 1), jnp.int32), self.pool,
+                jnp.asarray(t.tables), jnp.asarray(t.lengths), plan,
+                jnp.zeros((B, 1), jnp.float32), slot_w, back_w, ready, tplan,
+                resched).compile()
+        return {"prefill_step": prefill.as_text(),
+                "decode_step": decode.as_text(),
+                "write_prefill_blocks": write.as_text()}
+
     def profile_phases(self, iters: int = 3, impl: Optional[str] = None,
                        tokens: Optional[int] = None) -> Dict[str, float]:
         """Measure the per-step phase breakdown: the paged decode
@@ -1362,6 +1401,8 @@ class ContinuousEngine(_OverlapStoreMixin):
     # ------------------------------------------------------------------ step
     def submit(self, req: ServeRequest):
         self.scheduler.submit(req)
+        if self.tracer.enabled:
+            self.tracer.instant("request.submit", args={"rid": req.rid})
 
     def has_work(self) -> bool:
         return self.scheduler.has_work()
@@ -1382,34 +1423,38 @@ class ContinuousEngine(_OverlapStoreMixin):
         iter_counts = None
         prefill_tokens = 0
         ctx = self.mesh or _nullcontext()
-        step_args = {"iteration": self.iterations}
-        if self.model:
-            step_args["model"] = self.model
-        step_span = self.tracer.span("step", args=step_args)
+        tr = self.tracer
+        on = tr.enabled                  # no span args are built when off
+        step_span = tr.span("step", args=(
+            {"iteration": self.iterations,
+             **({"model": self.model} if self.model else {})}
+            if on else None))
         step_span.__enter__()
-        self._step_migration_bytes = 0.0
-        self._step_migration_hidden_bytes = 0.0
-        self._step_overflow = 0.0
-        self._step_dropped = 0.0
-        self._tick_migration()       # commit BEFORE this iteration's plan read
-        plan = self._current_plan()
-        resched = (self._resched_stack
-                   if self.lever in ("reschedule", "both") else None)
-        slot_w = self._store.weights if self._store is not None else None
-        back_w, ready, tplan = self._overlap_args()
+        with tr.span("plan"):
+            self._step_migration_bytes = 0.0
+            self._step_migration_hidden_bytes = 0.0
+            self._step_overflow = 0.0
+            self._step_dropped = 0.0
+            self._tick_migration()   # commit BEFORE this iteration's plan read
+            plan = self._current_plan()
+            resched = (self._resched_stack
+                       if self.lever in ("reschedule", "both") else None)
+            slot_w = self._store.weights if self._store is not None else None
+            back_w, ready, tplan = self._overlap_args()
 
-        with self.tracer.span("admission") as adm:
+        with tr.span("admission") as adm:
             splan: IterationPlan = sched.schedule(now)
-            adm.set_args(prefills=len(splan.prefills),
-                         decode_slots=len(splan.decode_slots),
-                         preempted=len(splan.preempted))
+            if on:
+                adm.set_args(prefills=len(splan.prefills),
+                             decode_slots=len(splan.decode_slots),
+                             preempted=len(splan.preempted))
         self._step_kind = "prefill" if splan.prefills else "decode"
 
         # ---------------------------------------------------------- prefill
         for req in splan.prefills:
-            pf_span = self.tracer.span(
-                "prefill", args={"rid": req.rid,
-                                 "prompt_len": req.prompt_len})
+            pf_span = tr.span("prefill", args=(
+                {"rid": req.rid, "prompt_len": req.prompt_len}
+                if on else None))
             pf_span.__enter__()
             slot = req.slot
             S = ccfg.prefill_len
@@ -1427,7 +1472,8 @@ class ContinuousEngine(_OverlapStoreMixin):
                     self._temp_cache, plan, pred, last, jnp.asarray(tw),
                     slot_w, back_w, ready, tplan, resched)
                 self.pool = self._write_fn(self.pool, temp, table)
-            tok0 = int(np.asarray(next_tok)[0, 0])
+            with tr.span("prefill.sync"):
+                tok0 = int(np.asarray(next_tok)[0, 0])
             req.generated.append(tok0)
             req.t_first_token = clock()
             self._last_tokens[slot] = tok0
@@ -1449,47 +1495,54 @@ class ContinuousEngine(_OverlapStoreMixin):
                         if sched.slots[s] is not None]
         attn_live = attn_alloc = 0.0
         if decode_slots:
-            # attention-compute roofline for this decode iteration, from
-            # the PRE-increment lengths the kernel actually sees: the
-            # gather oracle materializes and attends over every allocated
-            # table column (max_slots x tbl_m blocks) while the fused
-            # kernel's @pl.when(live) guard only computes blocks holding
-            # in-context (and, under a sliding window, in-window) tokens.
-            # alloc/live is the fused kernel's structural speedup bound.
-            bs = ccfg.block_size
-            tbl_m = sched.tables.tables.shape[1]
-            cl = sched.tables.lengths.astype(np.int64) + 1
-            starts = np.arange(tbl_m, dtype=np.int64)[None, :] * bs
-            live = starts < cl[:, None]
-            if self.cfg.sliding_window > 0:
-                live &= starts + bs > cl[:, None] - self.cfg.sliding_window
-            attn_live = float(live.sum())
-            attn_alloc = float(ccfg.max_slots * tbl_m)
-            active = np.zeros((ccfg.max_slots, 1), np.float32)
-            active[decode_slots] = 1.0
-            with self.tracer.span("decode",
-                                  args={"slots": len(decode_slots)}):
-                with ctx:
-                    next_tok, _, self.pool, stats = self._decode_fn(
-                        self.params, jnp.asarray(self._last_tokens[:, None]),
-                        self.pool, jnp.asarray(sched.tables.tables),
-                        jnp.asarray(sched.tables.lengths), plan,
-                        jnp.asarray(active), slot_w, back_w, ready, tplan,
-                        resched)
-            nt = np.asarray(next_tok)
-            for slot in decode_slots:
-                req = sched.slots[slot]
-                tok = int(nt[slot, 0])
-                req.generated.append(tok)
-                sched.tables.lengths[slot] += 1
-                self._last_tokens[slot] = tok
-            iter_counts = self._accumulate(iter_counts, stats)
-            events.decoded_slots = len(decode_slots)
-            for slot in decode_slots:
-                self._maybe_finish(slot, clock(), events)
+            dec_span = tr.span("decode", args=(
+                {"slots": len(decode_slots)} if on else None))
+            dec_span.__enter__()
+            with tr.span("decode.inputs"):
+                # attention-compute roofline for this decode iteration,
+                # from the PRE-increment lengths the kernel actually sees:
+                # the gather oracle materializes and attends over every
+                # allocated table column (max_slots x tbl_m blocks) while
+                # the fused kernel's @pl.when(live) guard only computes
+                # blocks holding in-context (and, under a sliding window,
+                # in-window) tokens. alloc/live is the fused kernel's
+                # structural speedup bound.
+                bs = ccfg.block_size
+                tbl_m = sched.tables.tables.shape[1]
+                cl = sched.tables.lengths.astype(np.int64) + 1
+                starts = np.arange(tbl_m, dtype=np.int64)[None, :] * bs
+                live = starts < cl[:, None]
+                if self.cfg.sliding_window > 0:
+                    live &= starts + bs > cl[:, None] - self.cfg.sliding_window
+                attn_live = float(live.sum())
+                attn_alloc = float(ccfg.max_slots * tbl_m)
+                active = np.zeros((ccfg.max_slots, 1), np.float32)
+                active[decode_slots] = 1.0
+                tokens_in = jnp.asarray(self._last_tokens[:, None])
+                tables_in = jnp.asarray(sched.tables.tables)
+                lengths_in = jnp.asarray(sched.tables.lengths)
+                active_in = jnp.asarray(active)
+            with tr.span("decode.launch"), ctx:
+                next_tok, _, self.pool, stats = self._decode_fn(
+                    self.params, tokens_in, self.pool, tables_in, lengths_in,
+                    plan, active_in, slot_w, back_w, ready, tplan, resched)
+            with tr.span("decode.sync"):
+                nt = np.asarray(next_tok)
+            with tr.span("decode.tokens"):
+                for slot in decode_slots:
+                    req = sched.slots[slot]
+                    tok = int(nt[slot, 0])
+                    req.generated.append(tok)
+                    sched.tables.lengths[slot] += 1
+                    self._last_tokens[slot] = tok
+                iter_counts = self._accumulate(iter_counts, stats)
+                events.decoded_slots = len(decode_slots)
+                for slot in decode_slots:
+                    self._maybe_finish(slot, clock(), events)
+            dec_span.__exit__()
 
         # ---------------------------------------------------------- observe
-        obs_span = self.tracer.span("observe")
+        obs_span = tr.span("observe")
         obs_span.__enter__()
         self.iterations += 1
         if self.cfg.is_moe and iter_counts is not None:
@@ -1558,31 +1611,35 @@ class ContinuousEngine(_OverlapStoreMixin):
         events.decision = decision
         obs_span.__exit__()
 
-        dt = clock() - now
-        self._recent_step_s = (dt if self._recent_step_s <= 0
-                               else 0.9 * self._recent_step_s + 0.1 * dt)
-        wall = _time.perf_counter() - t_wall0
-        if self._step_migration_bytes == 0:
-            # migration-free steps calibrate the overlap window (the
-            # compute time a staged fill can hide under). Measured on the
-            # WALL clock, not the driver's virtual clock — the window is a
-            # physical property of the forward pass, and frozen-clock
-            # drivers (tests, fixed-rate replay) would otherwise report 0.
-            # Keyed by iteration kind: a decode-only step must not inherit
-            # a prefill-sized window (and vice versa) — with the fused
-            # decode kernel the decode step wall is materially smaller, so
-            # the KindWindowEMA decode windows shrink to match.
-            self._serve_ema.update(self._step_kind, wall)
-        self.metrics.record_iteration(
-            now, dt, prefill_tokens=prefill_tokens,
-            decode_tokens=len(decode_slots),
-            counts=iter_counts, plan=self._plan_stack,
-            ep_ranks=self.ep_ranks,
-            dup_slots=self.moe_cfg.duplication_slots if self.moe_cfg else 0,
-            strategy=self.strategy, wall_s=wall,
-            attn_live_blocks=attn_live, attn_alloc_blocks=attn_alloc)
-        step_span.set_args(prefills=len(splan.prefills),
-                           decoded=len(decode_slots))
+        with tr.span("record"):
+            dt = clock() - now
+            self._recent_step_s = (dt if self._recent_step_s <= 0
+                                   else 0.9 * self._recent_step_s + 0.1 * dt)
+            wall = _time.perf_counter() - t_wall0
+            if self._step_migration_bytes == 0:
+                # migration-free steps calibrate the overlap window (the
+                # compute time a staged fill can hide under). Measured on
+                # the WALL clock, not the caller's virtual clock — the
+                # window is a physical property of the forward pass, and
+                # frozen-clock callers (tests, fixed-rate replay) would
+                # otherwise report 0. Keyed by iteration kind: a
+                # decode-only step must not inherit a prefill-sized window
+                # (and vice versa) — with the fused decode kernel the
+                # decode step wall is materially smaller, so the
+                # KindWindowEMA decode windows shrink to match.
+                self._serve_ema.update(self._step_kind, wall)
+            self.metrics.record_iteration(
+                now, dt, prefill_tokens=prefill_tokens,
+                decode_tokens=len(decode_slots),
+                counts=iter_counts, plan=self._plan_stack,
+                ep_ranks=self.ep_ranks,
+                dup_slots=(self.moe_cfg.duplication_slots
+                           if self.moe_cfg else 0),
+                strategy=self.strategy, wall_s=wall,
+                attn_live_blocks=attn_live, attn_alloc_blocks=attn_alloc)
+        if on:
+            step_span.set_args(prefills=len(splan.prefills),
+                               decoded=len(decode_slots))
         step_span.__exit__()
         return events
 

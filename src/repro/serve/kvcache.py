@@ -64,7 +64,8 @@ def write_prefill_blocks(pool: Dict[str, jnp.ndarray],
         bs = p.shape[2]
         blocks = t.reshape(L, S // bs, bs, K, hd)
         return p.at[:, table].set(blocks.astype(p.dtype))
-    return jax.tree.map(upd, pool, temp)
+    with jax.named_scope("attn.kv_write"):
+        return jax.tree.map(upd, pool, temp)
 
 
 class BlockAllocator:

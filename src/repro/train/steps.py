@@ -155,7 +155,8 @@ def make_slot_prefill_step(cfg: ModelConfig, rt: Runtime):
                                        slot_ready=slot_ready,
                                        target_plan=target_plan,
                                        resched=resched)
-        next_tok = logits[:, -1].argmax(-1).astype(jnp.int32)[:, None]
+        with jax.named_scope("lm_head"):
+            next_tok = logits[:, -1].argmax(-1).astype(jnp.int32)[:, None]
         return next_tok, logits, cache, stats
     return prefill_step
 
@@ -180,7 +181,8 @@ def make_paged_decode_step(cfg: ModelConfig, rt: Runtime):
                                       slot_ready=slot_ready,
                                       target_plan=target_plan,
                                       resched=resched)
-        next_tok = logits[:, -1].argmax(-1).astype(jnp.int32)[:, None]
+        with jax.named_scope("lm_head"):
+            next_tok = logits[:, -1].argmax(-1).astype(jnp.int32)[:, None]
         return next_tok, logits, pool, stats
     return decode_step
 
