@@ -1,19 +1,18 @@
 """Share of the HBM roofline reached by the whole decode program: the
 bytes a decode step must read (weights of the experts its routing hit,
-the attention, router and norm weights of the held layers, the LM head,
-the live KV) over its device time at peak HBM bandwidth, per chip.
-Experts hit come from decode-only steps' expert counts; a step that also
-prefilled is charged the mean of those."""
+what it reads whatever the routing, the live KV: the architecture's
+``expert_bytes``, ``fixed_bytes`` and ``kv_read_bytes``) over its device
+time at peak HBM bandwidth, per chip. Experts hit come from decode-only
+steps' expert counts; a step that also prefilled is charged the mean of
+those."""
 
 import numpy as np
-
-import counts
 
 
 def read(ctx):
     if ctx.trace is None:
         return None
-    D = ctx.D
+    A, D = ctx.arch, ctx.D
     steps = [s for s in ctx.steps if s.decodes]
     hits = [int((s.counts > 0).sum()) for s in ctx.steps
             if s.decodes and not s.prefills and s.counts is not None]
@@ -24,9 +23,8 @@ def read(ctx):
     for s in steps:
         h = (int((s.counts > 0).sum()) if not s.prefills
              and s.counts is not None else mean_hits)
-        need += (h * counts.expert_bytes(D)
-                 + D["L"] * counts.layer_dense_bytes(D) + counts.head_bytes(D)
-                 + sum(counts.kv_read_bytes(D, c, ctx.block_size)
+        need += (h * A.expert_bytes(D) + A.fixed_bytes(D)
+                 + sum(A.kv_read_bytes(D, c, ctx.block_size)
                        for c in s.decodes))
     ns, _ = ctx.trace.modules_matching(r"decode_step")
     if not ns:

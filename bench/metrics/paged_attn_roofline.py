@@ -1,10 +1,9 @@
 """Share of the HBM roofline reached by the fused paged-decode attention
 kernel: the bytes of the live KV blocks each call must read (from the
 harness's record of every decoding sequence's context) over the kernel's
-device time at peak HBM bandwidth. Per chip: a chip holds its share of
-the KV heads."""
+device time at peak HBM bandwidth (the architecture's ``kv_read_bytes``).
+Per chip: a chip holds its share of the KV heads."""
 
-import counts
 import kernels
 
 
@@ -12,7 +11,7 @@ def read(ctx):
     if ctx.trace is None:
         return None
     ns = ctx.trace.ops_matching(kernels.PAGED_ATTENTION)
-    need = sum(counts.kv_read_bytes(ctx.D, c, ctx.block_size)
+    need = sum(ctx.arch.kv_read_bytes(ctx.D, c, ctx.block_size)
                for s in ctx.steps for c in s.decodes)
     if not ns or not need:
         return None

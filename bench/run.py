@@ -3,7 +3,10 @@
     python3 bench/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
 A cell names a configuration (``bench/configs/<file>.json``) and a
-traffic mix (``bench/traffic/<mix>.json``). A run:
+traffic mix (``bench/traffic/<mix>.json``). The configuration names its
+architecture by the published config's ``model_type``, and
+``bench/arch/<model_type>.py`` holds what is that architecture's alone
+(see ``load_arch``). A run:
 
 1. enables JAX's persistent compilation cache at its fixed path
    (``$JAX_COMPILATION_CACHE_DIR``, else ``<checkout>/.jax_cache``);
@@ -38,6 +41,7 @@ import gc  # noqa: E402
 import importlib.util  # noqa: E402
 import json  # noqa: E402
 import os  # noqa: E402
+import re  # noqa: E402
 import shutil  # noqa: E402
 import sys  # noqa: E402
 import tempfile  # noqa: E402
@@ -46,6 +50,7 @@ from typing import List, Optional  # noqa: E402
 
 BENCH = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(BENCH)
+ARCH = os.path.join(BENCH, "arch")
 sys.path.insert(0, BENCH)
 sys.path.insert(1, os.path.join(ROOT, "src"))
 
@@ -66,20 +71,50 @@ def load_spec() -> dict:
         return json.load(f)
 
 
-def resolve(spec: dict, workload: str):
-    """(cell, configuration file contents, traffic mix) of a cell."""
+def resolve(spec: dict, workload: str, arch_dir: str = ARCH):
+    """(cell, configuration file contents, traffic mix, architecture
+    module) of a cell."""
     cell = next((w for w in spec["workloads"] if w["name"] == workload), None)
     if cell is None:
         raise SystemExit(f"unknown workload {workload!r}")
     entry = next(c for c in spec["configs"] if c["name"] == cell["config"])
-    conf = mix = None
-    if entry.get("file"):
-        with open(os.path.join(ROOT, entry["file"])) as f:
-            conf = json.load(f)
+    with open(os.path.join(ROOT, entry["file"])) as f:
+        conf = json.load(f)
+    mix = None
     if os.path.exists(os.path.join(BENCH, "traffic",
                                    f"{cell['traffic']}.json")):
         mix = T.load_mix(cell["traffic"])
-    return cell, conf, mix
+    return cell, conf, mix, load_arch(conf, arch_dir)
+
+
+def load_arch(conf: dict, arch_dir: str = ARCH):
+    """The architecture module of a configuration: ``<arch_dir>/
+    <model_type>.py``, loaded by path (once per path and process).
+
+    It holds what is that architecture's alone: ``PUBLISHED`` (the
+    published widths of each ``source``), ``TENSORS`` (a tensor's index
+    there seeds its weights), ``dims(conf)`` (sizes under short names,
+    ``V`` the vocabulary), ``model_config(conf, engine)`` (the program's
+    ``ModelConfig``), ``serving_tree(root, D)`` and ``outer_tensors(root,
+    D)`` (drawn by ``weights.draw``), ``forward_layers(conf, root, xs,
+    seqs, control)`` (the reference's layers) and the counts
+    ``expert_bytes``, ``fixed_bytes``, ``kv_read_bytes``,
+    ``prefill_flops`` and ``decode_flops``."""
+    mt = conf.get("model_type")
+    named = isinstance(mt, str) and re.fullmatch(r"\w+", mt)
+    path = os.path.join(arch_dir, f"{mt if named else '<model_type>'}.py")
+    shown = os.path.relpath(path, ROOT) if path.startswith(ROOT) else path
+    if not (named and os.path.isfile(path)):
+        raise SystemExit(f"configuration {conf.get('name')!r} (model_type "
+                         f"{mt!r}) needs its architecture module {shown}")
+    name = f"bench_arch_{mt}"
+    mod = sys.modules.get(name)
+    if mod is None or mod.__file__ != path:
+        sp = importlib.util.spec_from_file_location(name, path)
+        mod = importlib.util.module_from_spec(sp)
+        sp.loader.exec_module(mod)
+        sys.modules[name] = mod
+    return mod
 
 
 def per_layer_metrics(spec: dict, workload: str) -> List[dict]:
@@ -108,25 +143,9 @@ def load_reader(name: str):
 
 # ---------------------------------------------------------------- model
 
-def model_config(conf: dict):
+def model_config(conf: dict, arch_dir: str = ARCH):
     """The program's ``ModelConfig`` for a configuration file."""
-    from repro.configs.base import ModelConfig, MoEConfig
-    eng = conf["engine"]
-    return ModelConfig(
-        name=conf["name"], family="moe",
-        num_layers=conf["num_hidden_layers"], d_model=conf["hidden_size"],
-        num_heads=conf["num_attention_heads"],
-        num_kv_heads=conf["num_key_value_heads"], head_dim=conf["head_dim"],
-        d_ff=conf["intermediate_size"], vocab_size=conf["vocab_size"],
-        attention="gqa", sliding_window=conf["sliding_window"] or 0,
-        rope_theta=float(conf["rope_theta"]), norm="rmsnorm",
-        activation="swiglu", tie_embeddings=conf["tie_word_embeddings"],
-        moe=MoEConfig(num_experts=conf["num_local_experts"],
-                      top_k=conf["num_experts_per_tok"],
-                      d_ff_expert=conf["intermediate_size"],
-                      capacity_factor=float(eng["capacity_factor"]),
-                      max_copies=eng["max_copies"]),
-        source=conf["source"])
+    return load_arch(conf, arch_dir).model_config(conf, conf["engine"])
 
 
 def engine_config(conf: dict, mix: dict):
@@ -187,10 +206,12 @@ def recorder_class():
 
 
 class Context:
-    """What a per-layer reader gets: the configuration (``conf``, its sizes
-    ``D``), the mix, ``chips``, the device's ``peak`` row, the window's
-    ``steps`` and ``requests``, the engine's ``spans`` on the trace's
-    clock, the reduced ``trace`` (None without one) and ``block_size``.
+    """What a per-layer reader gets: the configuration (``conf``, its
+    architecture module ``arch``, whose counts the readers call, and its
+    sizes ``D``), the mix, ``chips``, the device's ``peak`` row, the
+    window's ``steps`` and ``requests``, the engine's ``spans`` on the
+    trace's clock, the reduced ``trace`` (None without one) and
+    ``block_size``.
     The traced run traces all of its window."""
 
     def __init__(self, **kw):
@@ -389,7 +410,7 @@ def gap_stats(gaps: np.ndarray) -> dict:
             "mismatch_share": float((gaps > 0).mean())}
 
 
-def check(conf: dict, seed: int, picked: List[Pick], extra: dict,
+def check(arch, conf: dict, seed: int, picked: List[Pick], extra: dict,
           control: bool = False):
     """Numbers compared, each as (value, limit, passes), and every gap
     reading. The configuration's ``limits`` say which gap readings are
@@ -402,7 +423,7 @@ def check(conf: dict, seed: int, picked: List[Pick], extra: dict,
     seqs = [np.concatenate([p.req.prompt,
                             np.asarray(p.req.served[:p.fed], np.int32)])
             for p in picked]
-    out = reference.teacher_forced(conf, seed, seqs,
+    out = reference.teacher_forced(arch, conf, seed, seqs,
                                    [p.checked for p in picked],
                                    control=control)
     readings = gap_stats(out["control_gap" if control else "gap"])
@@ -420,7 +441,7 @@ def check(conf: dict, seed: int, picked: List[Pick], extra: dict,
 
 # ------------------------------------------------------------------ run
 
-def build(conf: dict, mix: dict, seed: int, devices, trace: bool):
+def build(arch, conf: dict, mix: dict, seed: int, devices, trace: bool):
     """Weights from the seed and one warm engine on ``devices``. Returns
     (engine, recorder, tracer)."""
     import jax
@@ -433,12 +454,12 @@ def build(conf: dict, mix: dict, seed: int, devices, trace: bool):
     mesh = make_dev_mesh(conf["mesh"]["data"], conf["mesh"]["model"],
                          devices=devices)
     t = time.perf_counter()
-    params = jax.block_until_ready(W.serving_params(conf, seed, mesh))
+    params = jax.block_until_ready(W.serving_params(arch, conf, seed, mesh))
     t_weights = time.perf_counter() - t
     tracer = SpanTracer(capacity=1 << 20) if trace else None
     recorder = recorder_class()()
-    engine = ContinuousEngine(model_config(conf), params,
-                              engine_config(conf, mix), mesh=mesh,
+    engine = ContinuousEngine(arch.model_config(conf, conf["engine"]),
+                              params, engine_config(conf, mix), mesh=mesh,
                               ep_ranks=conf["ep_ranks"], tracer=tracer,
                               metrics=recorder)
     engine.warmup()
@@ -469,16 +490,16 @@ def open_devices(chips: int, require_tpu: bool = True):
 
 def run(workload: str, seed: int, seconds: float, trace: bool, *,
         require_tpu: bool = True, spec: Optional[dict] = None,
-        conf: Optional[dict] = None, mix: Optional[dict] = None,
-        engine_hook=None, control: bool = False) -> dict:
+        mix: Optional[dict] = None, engine_hook=None,
+        control: bool = False) -> dict:
     """One run of a cell; returns the result object (``None`` where the
-    device check fails). ``conf``/``mix`` replace the cell's files, and
+    device check fails). ``mix`` replaces the cell's traffic file, and
     ``engine_hook`` is called with the warm engine (``faults.py`` breaks
     the timed path through it); with ``control`` the fp8 control is judged
     in the program's place."""
     spec = spec or load_spec()
-    cell, conf0, mix0 = resolve(spec, workload)
-    conf, mix = conf or conf0, mix or mix0
+    cell, conf, mix0, arch = resolve(spec, workload)
+    mix = mix or mix0
     chips = cell["chips"]
 
     devices = open_devices(chips, require_tpu)
@@ -487,9 +508,8 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *,
     t_devices = time.perf_counter() - T_START
     import jax
 
-    import weights as W
-    D = W.dims(conf)
-    engine, recorder, tracer = build(conf, mix, seed, devices, trace)
+    D = arch.dims(conf)
+    engine, recorder, tracer = build(arch, conf, mix, seed, devices, trace)
     if engine_hook is not None:
         engine_hook(engine)
     drv = ServeLoop(engine, T.Traffic(mix, D["V"], seed), mix, recorder)
@@ -537,7 +557,8 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *,
     print(f"window {end:.3f} s: {served}; setup {setup_s:.2f} s; "
           f"peak {peak / 1e9:.3f} GB", file=sys.stderr)
     t_ref = time.perf_counter()
-    checks, readings = check(conf, seed, picked, extra, control=control)
+    checks, readings = check(arch, conf, seed, picked, extra,
+                             control=control)
     correct = all(ok for _, _, ok in checks.values())
     print(f"reference {time.perf_counter() - t_ref:.2f} s", file=sys.stderr)
 
@@ -552,8 +573,8 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *,
                              for m in spec["end_to_end"]
                              if workload in m.get("workloads", [workload])}
     else:
-        result.update(per_layer(spec, workload, conf, mix, chips, steps,
-                                reqs, spans, log_dir, t_win, device))
+        result.update(per_layer(spec, workload, arch, conf, mix, chips,
+                                steps, reqs, spans, log_dir, t_win, device))
         shutil.rmtree(log_dir, ignore_errors=True)
     result["device"] = device
     result["served"] = served
@@ -563,11 +584,10 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *,
     return result
 
 
-def per_layer(spec, workload, conf, mix, chips, steps, reqs, spans, log_dir,
-              t_win, device):
+def per_layer(spec, workload, arch, conf, mix, chips, steps, reqs, spans,
+              log_dir, t_win, device):
     """Per-layer metrics and the breakdown of a traced run."""
     import trace_reduce as TR
-    import weights as W
     with open(os.path.join(BENCH, "peaks.json")) as f:
         peaks = json.load(f)
     if device["kind"] not in peaks:
@@ -586,8 +606,9 @@ def per_layer(spec, workload, conf, mix, chips, steps, reqs, spans, log_dir,
         n = min(len(t0s), len(red.steps))
         off = int(np.median(red.steps[:n, 0] - t0s[:n]))
         aligned = [(ts + off, dur, name) for ts, dur, name in spans]
-    ctx = Context(conf=conf, D=W.dims(conf), mix=mix, chips=chips,
-                  peak=peaks[device["kind"]], steps=steps, requests=reqs,
+    ctx = Context(conf=conf, arch=arch, D=arch.dims(conf), mix=mix,
+                  chips=chips, peak=peaks[device["kind"]], steps=steps,
+                  requests=reqs,
                   spans=aligned, trace=red,
                   block_size=conf["engine"]["block_size"])
     metrics = {}

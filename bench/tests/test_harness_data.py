@@ -1,5 +1,7 @@
-"""The harness as data: every cell resolves its files by name, and the
-traffic is a function of the seed that never outgrows the prefill bucket."""
+"""The harness as data: every cell resolves its files by name (its
+architecture module by the configuration's ``model_type``), and the
+traffic is a function of the seed that never outgrows the prefill bucket
+or the configuration's vocabulary."""
 
 import glob
 import json
@@ -18,9 +20,10 @@ BIG_SEED = 2 ** 31 + 12345
 
 @pytest.mark.parametrize("cell", CELLS)
 def test_cell_resolves_its_files(cell):
-    c, conf, mix = R.resolve(SPEC, cell)
+    c, conf, mix, arch = R.resolve(SPEC, cell)
     entry = next(e for e in SPEC["configs"] if e["name"] == c["config"])
     assert entry["file"].startswith("bench/configs/")
+    assert arch.__file__ == os.path.join(R.ARCH, f"{conf['model_type']}.py")
     assert set(entry["reduced"]) <= set(conf["reduced"])
     assert conf["mesh"]["data"] * conf["mesh"]["model"] == c["chips"]
     assert mix["slots"]["prefill_len"] <= mix["slots"]["max_len"]
@@ -42,15 +45,16 @@ def test_every_metric_has_a_reader_and_its_cells_exist():
                                            f"{w['traffic']}.json"))
 
 
-def _requests(mix, seed, n=130):
-    return T.Traffic(mix, 32000, seed).first(n)
+def _requests(cell, seed, n=130):
+    """The cell's first ``n`` requests, ids drawn from its vocabulary."""
+    _, conf, mix, arch = R.resolve(SPEC, cell)
+    return T.Traffic(mix, arch.dims(conf)["V"], seed).first(n)
 
 
 @pytest.mark.parametrize("cell", CELLS)
 def test_one_seed_gives_the_same_requests(cell):
-    _, _, mix = R.resolve(SPEC, cell)
-    a, b = _requests(mix, BIG_SEED), _requests(mix, BIG_SEED)
-    c = _requests(mix, BIG_SEED + 1)
+    a, b = _requests(cell, BIG_SEED), _requests(cell, BIG_SEED)
+    c = _requests(cell, BIG_SEED + 1)
     assert all(np.array_equal(x.prompt, y.prompt) and x.max_new == y.max_new
                for x, y in zip(a, b))
     assert any(not np.array_equal(x.prompt, y.prompt)
@@ -59,14 +63,15 @@ def test_one_seed_gives_the_same_requests(cell):
 
 @pytest.mark.parametrize("cell", CELLS)
 def test_prompts_fit_the_bucket_and_decks_hold_the_same_sizes(cell):
-    _, _, mix = R.resolve(SPEC, cell)
+    _, conf, mix, arch = R.resolve(SPEC, cell)
     deck = mix["deck"]
     limit = mix["slots"]["prefill_len"]
     sizes = []
     for seed in (1, BIG_SEED):
-        reqs = _requests(mix, seed, 2 * deck)
+        reqs = _requests(cell, seed, 2 * deck)
         assert max(len(r.prompt) for r in reqs) <= limit
         assert min(len(r.prompt) for r in reqs) >= 1
+        assert max(int(r.prompt.max()) for r in reqs) < arch.dims(conf)["V"]
         assert max(len(r.prompt) + r.max_new for r in reqs) \
             <= mix["slots"]["max_len"]
         sizes.append(sorted((len(r.prompt), r.max_new) for r in reqs[:deck]))
@@ -104,14 +109,17 @@ def test_hot_topic_moves():
 
 
 def test_config_files_hold_the_published_widths():
-    for path in glob.glob(os.path.join(R.BENCH, "configs", "*.json")):
+    """Each file holds the widths its architecture module publishes for
+    its ``source``, but for the keys its ``reduced`` names."""
+    paths = glob.glob(os.path.join(R.BENCH, "configs", "*.json"))
+    assert paths
+    for path in paths:
         with open(path) as f:
             conf = json.load(f)
-        assert (conf["hidden_size"], conf["intermediate_size"],
-                conf["num_attention_heads"], conf["num_key_value_heads"],
-                conf["head_dim"], conf["num_local_experts"],
-                conf["num_experts_per_tok"], conf["vocab_size"]) \
-            == (4096, 14336, 32, 8, 128, 8, 2, 32000)
+        published = R.load_arch(conf).PUBLISHED[conf["source"]]
+        for key, width in published.items():
+            if key not in conf["reduced"]:
+                assert conf[key] == width, (path, key)
 
 
 def test_no_tpu_means_no_result(capsys):
