@@ -21,6 +21,9 @@ class MoEConfig:
     top_k: int
     d_ff_expert: int
     num_shared_experts: int = 0          # always-on experts (deepseek-style)
+    # routed gates: the top-k softmax probabilities, renormalised to sum to
+    # one over the k (Mixtral) or left as they are (DeepSeek-V2)
+    norm_topk_prob: bool = True
     dense_residual: bool = False         # arctic: dense FFN in parallel with MoE
     d_ff_dense: int = 0                  # width of the dense residual branch
     capacity_factor: float = 1.25
@@ -71,6 +74,17 @@ class MLAConfig:
 
 
 @dataclass(frozen=True)
+class YarnConfig:
+    """YaRN rotary scaling (DeepSeek-V2's ``rope_scaling``, type "yarn")."""
+    factor: float = 40.0
+    original_max_position: int = 4096
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 0.707
+    mscale_all_dim: float = 0.707
+
+
+@dataclass(frozen=True)
 class EncoderConfig:
     """Encoder stack for enc-dec (seamless-m4t) architectures."""
     num_layers: int
@@ -97,6 +111,7 @@ class ModelConfig:
     qkv_bias: bool = False
     sliding_window: int = 0              # 0 = full attention
     rope_theta: float = 10000.0
+    yarn: Optional[YarnConfig] = None    # YaRN-scaled rotary frequencies
     # Paged decode attention ------------------------------------------------
     # "fused": single Pallas pass walks block_tables and computes GQA
     # attention with an online-softmax accumulator straight from the shared
@@ -108,6 +123,10 @@ class ModelConfig:
     norm: str = "rmsnorm"                # rmsnorm | nonparametric (olmo)
     activation: str = "swiglu"           # swiglu | gelu | relu | relu2 (rwkv)
     tie_embeddings: bool = False
+    # leading layers whose FFN is dense (width ``d_ff``) ahead of the MoE
+    # stack (DeepSeek-V2's ``first_k_dense_replace``); their parameters
+    # and caches sit apart from the uniform stack they precede
+    first_k_dense: int = 0
     # family extensions ------------------------------------------------------
     moe: Optional[MoEConfig] = None
     mla: Optional[MLAConfig] = None
@@ -132,6 +151,13 @@ class ModelConfig:
     @property
     def is_moe(self) -> bool:
         return self.moe is not None
+
+    @property
+    def num_moe_layers(self) -> int:
+        """Layers of the uniform (scanned) stack after the leading dense
+        ones: the leading dim of expert counts, plans and stacked
+        layer params."""
+        return self.num_layers - self.first_k_dense
 
     @property
     def is_encdec(self) -> bool:
@@ -178,6 +204,14 @@ class ModelConfig:
             ff_mult = 3 if self.activation == "swiglu" else 2
             per_layer += ff_mult * d * self.d_ff
         total = emb + L * per_layer
+        if self.moe is not None and self.first_k_dense:
+            # leading layers: a dense FFN where the others hold experts
+            e = self.moe
+            moe_ffn = ((e.num_experts + e.num_shared_experts) * ff_mult * d
+                       * e.d_ff_expert + d * e.num_experts)
+            if e.dense_residual:
+                moe_ffn += ff_mult * d * (e.d_ff_dense or self.d_ff)
+            total += self.first_k_dense * (ff_mult * d * self.d_ff - moe_ffn)
         if self.encoder is not None:
             enc = self.encoder
             enc_layer = 4 * enc.d_model * enc.num_heads * (enc.d_model // enc.num_heads)
@@ -192,12 +226,14 @@ class ModelConfig:
         e = self.moe
         ff_mult = 3 if self.activation == "swiglu" else 2
         inactive = (e.num_experts - e.top_k) * ff_mult * self.d_model * e.d_ff_expert
-        return self.num_params() - self.num_layers * inactive
+        return self.num_params() - self.num_moe_layers * inactive
 
     def reduced(self) -> "ModelConfig":
         """CPU-smoke variant: same family/features, tiny dims."""
         changes = dict(
+            # a leading dense layer stays, ahead of one layer of the stack
             num_layers=min(self.num_layers, 2),
+            first_k_dense=min(self.first_k_dense, 1),
             d_model=min(self.d_model, 256),
             num_heads=min(self.num_heads, 4),
             num_kv_heads=min(self.num_kv_heads, 2),

@@ -11,6 +11,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels import histogram as _hist
+from repro.kernels import latent_attention as _la
 from repro.kernels import moe_gemm as _mg
 from repro.kernels import paged_attention as _pa
 from repro.kernels import rg_lru as _rg
@@ -84,3 +85,29 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, lengths, *,
                                    P(b)),
                          out_specs=q_spec, check_vma=False)(
         q, k_pool, v_pool, block_tables, lengths)
+
+
+def latent_decode_attention(q, pool, block_tables, lengths, *, r: int,
+                            scale: float, mesh=None):
+    """Paged absorbed-MLA decode over the latent pool — the rows DMA'd a
+    chunk of pages at a time, key and value read from the same row (see
+    `repro.kernels.latent_attention`). q: (B, H, D); pool: (N, bs, D).
+
+    ``mesh``: the kernel runs under ``shard_map`` over it, slots over the
+    batch axes when they divide evenly, heads over "model" when it divides
+    them; the pool (no head dim) whole on every device."""
+    def kernel(q_, p_, t_, l_):
+        return _la.latent_decode_attention(q_, p_, t_, l_, r=r, scale=scale,
+                                           interpret=_interpret())
+
+    if mesh is None:
+        return kernel(q, pool, block_tables, lengths)
+    from jax.sharding import PartitionSpec as P
+    from repro.sharding import batch_axes_for, kv_head_axis
+    B, H = q.shape[:2]
+    b = batch_axes_for(mesh, B)
+    q_spec = P(b, kv_head_axis(mesh, H), None)
+    return jax.shard_map(kernel, mesh=mesh,
+                         in_specs=(q_spec, P(), P(b, None), P(b)),
+                         out_specs=q_spec, check_vma=False)(
+        q, pool, block_tables, lengths)

@@ -90,6 +90,50 @@ def paged_decode_ref(q, k_view, v_view, lengths, *, window: int = 0,
     return (acc / jnp.maximum(l_f, 1e-20)[..., None]).astype(q.dtype)
 
 
+def latent_decode_ref(q, view, lengths, *, r: int, scale: float,
+                      chunk: int):
+    """Gather-path oracle for ``kernels.latent_attention``: the kernel's
+    chunked online softmax (bf16 operands, f32 accumulation) over the
+    MATERIALISED logical view of each slot's latent rows.
+
+    q: (B, H, D); view: (B, M*bs, D) gathered rows; lengths: (B,) int32
+    (new row already written at ``lengths[b]``); ``chunk``: positions per
+    kernel step (pages x block size). Returns (B, H, r) in q's dtype."""
+    B, H, D = q.shape
+    S = view.shape[1]
+    pad = -S % chunk
+    view = jnp.pad(view, ((0, 0), (0, pad), (0, 0)))
+    n = (S + pad) // chunk
+    cl = jnp.asarray(lengths, jnp.int32) + 1                      # (B,)
+    neg_inf = -1e30
+
+    def step(carry, inputs):
+        m_run, l_run, acc = carry
+        ci, kv = inputs                                           # (B, chunk, D)
+        s = jnp.einsum("bhd,bcd->bhc", q, kv,
+                       preferred_element_type=jnp.float32) * scale
+        pos = ci * chunk + jnp.arange(chunk, dtype=jnp.int32)
+        s = jnp.where(pos[None, None, :] < cl[:, None, None], s, neg_inf)
+        m_new = jnp.maximum(m_run, s.max(axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        corr = jnp.exp(m_run - m_new)
+        l_new = l_run * corr + p.sum(axis=-1, keepdims=True)
+        a_new = acc * corr + jnp.einsum(
+            "bhc,bcr->bhr", p.astype(kv.dtype), kv[..., :r],
+            preferred_element_type=jnp.float32)
+        live = (ci * chunk < cl)[:, None, None]
+        return (jnp.where(live, m_new, m_run), jnp.where(live, l_new, l_run),
+                jnp.where(live, a_new, acc)), None
+
+    m0 = jnp.full((B, H, 1), neg_inf, jnp.float32)
+    l0 = jnp.zeros((B, H, 1), jnp.float32)
+    a0 = jnp.zeros((B, H, r), jnp.float32)
+    kvs = jnp.moveaxis(view.reshape(B, n, chunk, D), 1, 0)
+    (_, l_f, acc), _ = jax.lax.scan(
+        step, (m0, l0, a0), (jnp.arange(n, dtype=jnp.int32), kvs))
+    return (acc / jnp.maximum(l_f, 1e-20)).astype(q.dtype)
+
+
 def rg_lru_ref(a, b, h0):
     """Sequential linear recurrence h_t = a_t h_{t-1} + b_t.
     a, b: (B, S, D) f32; h0: (B, D) f32. Returns (h_all (B,S,D), h_last)."""

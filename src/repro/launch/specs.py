@@ -66,7 +66,7 @@ def plan_args(cfg: ModelConfig, ep_ranks: int):
         return None
     m = cfg.moe
     plans = [identity_plan(m.num_experts, ep_ranks, m.duplication_slots,
-                           m.max_copies) for _ in range(cfg.num_layers)]
+                           m.max_copies) for _ in range(cfg.num_moe_layers)]
     return stack_plans(plans)
 
 
@@ -116,7 +116,8 @@ def abstract_params(cfg: ModelConfig, mesh: Mesh, *, dtype=jnp.bfloat16,
     struct = _cast_tree(struct, dtype)
     fsdp_axes = batch_axes(mesh) if fsdp else ()
     fsdp_size = int(np.prod([mesh.shape[a] for a in fsdp_axes])) or 1
-    specs = param_specs(struct, stacked_prefixes=("layers", "enc_layers"),
+    specs = param_specs(struct, stacked_prefixes=("layers", "dense_layers",
+                                                  "enc_layers"),
                         fsdp_axes=fsdp_axes, fsdp_size=fsdp_size, mesh=mesh,
                         expert_tp_axes=batch_axes(mesh) if expert_tp else ())
     return _sds(struct, specs, mesh), specs
@@ -155,7 +156,7 @@ def cache_specs(cfg: ModelConfig, cache_struct, mesh: Mesh, B: int):
             if nd == 4:                                  # hybrid: (B,W,KV,hd)
                 kv_ok = cfg.num_kv_heads % m == 0
                 return P(b, None, "model" if kv_ok else None, None)
-        if "c_kv" in path or "k_rope" in path:           # MLA: (L,B,C,r)
+        if "latent" in path:                             # MLA: (L,B,C,D)
             return P(None, b, None, None)
         if "wkv" in path:                                # rwkv: (L,B,H,hd,hd)
             h_ok = leaf.shape[2] % m == 0

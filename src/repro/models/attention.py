@@ -16,7 +16,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import ModelConfig
-from repro.models.layers import apply_rope, dense, init_dense
+from repro.models.layers import (apply_rope, dense, init_dense, init_rmsnorm,
+                                 rmsnorm, yarn_mscale)
 
 NEG_INF = -1e30
 
@@ -36,18 +37,20 @@ def _block_mask(q_pos, k_pos, causal: bool, window: int):
 
 
 def chunked_attention(q, k, v, *, causal=True, window=0, q_offset=0,
-                      kv_valid_len=None, q_block=512, kv_block=512):
+                      kv_valid_len=None, q_block=512, kv_block=512,
+                      scale=None):
     """Flash-style attention without materialising (Sq, Skv) for full seqs.
 
     q: (B, Sq, H, hd); k, v: (B, Skv, K, hd) with H % K == 0 (GQA).
     q_offset: absolute position of q[0] (for decode / continued prefill).
     kv_valid_len: optional scalar — keys at positions >= this are masked.
+    scale: softmax scale of the scores (default ``hd ** -0.5``).
     Returns (B, Sq, H, hd).
     """
     B, Sq, H, hd = q.shape
     _, Skv, K, _ = k.shape
     G = H // K
-    scale = 1.0 / math.sqrt(hd)
+    scale = 1.0 / math.sqrt(hd) if scale is None else scale
 
     q_block = min(q_block, Sq)
     kv_block = min(kv_block, Skv)
@@ -354,63 +357,94 @@ def gqa_decode_windowed(params, cfg: ModelConfig, x, cache, cache_len, *,
 
 
 # ---------------------------------------------------------------------------
-# MLA (DeepSeek-V2) — compressed-latent KV cache
+# MLA (DeepSeek-V2) — multi-head latent attention over a latent cache
 # ---------------------------------------------------------------------------
+#
+# The published block (``DeepseekV2Attention``, no q LoRA): q = x W_q split
+# into (q_nope, q_pe) per head; ``kv_a_proj_with_mqa`` gives the latent c
+# (kv_lora_rank wide, then RMSNorm'd) and one shared k_pe; ``kv_b_proj``
+# expands c into per-head k_nope (``w_uk``) and v (``w_uv``); RoPE (YaRN)
+# on q_pe and k_pe; softmax scale (qk head dim)^-0.5 x mscale^2.
+#
+# The cache keeps one row per token: [c, roped k_pe], zero-padded to a
+# multiple of 128 lanes (``mla_row_width``; the TPU tiles a row's minor
+# dim to 128 lanes either way, so the pad costs no memory, and the paged
+# kernel's DMA moves whole tiles). Prefill attends in the expanded form;
+# decode in the absorbed one: q_nope . W_uk^T joins q_pe as a query over
+# the rows, and W_uv is applied to the attended latent after.
+
+def mla_row_width(cfg: ModelConfig) -> int:
+    m = cfg.mla
+    return -(-(m.kv_lora_rank + m.rope_head_dim) // 128) * 128
+
+
+def mla_softmax_scale(cfg: ModelConfig) -> float:
+    m = cfg.mla
+    scale = (m.nope_head_dim + m.rope_head_dim) ** -0.5
+    y = cfg.yarn
+    if y is not None and y.mscale_all_dim:
+        scale *= yarn_mscale(y.factor, y.mscale_all_dim) ** 2
+    return scale
+
 
 def init_mla(key, cfg: ModelConfig):
     m = cfg.mla
     d, H = cfg.d_model, cfg.num_heads
-    keys = jax.random.split(key, 6)
-    p = {
-        "w_dkv": init_dense(keys[0], d, m.kv_lora_rank),          # KV down-proj
-        "w_krope": init_dense(keys[1], d, m.rope_head_dim),       # shared rope key
+    keys = jax.random.split(key, 5)
+    return {
+        "w_q": init_dense(keys[0], d, H * (m.nope_head_dim + m.rope_head_dim)),
+        # kv_a_proj_with_mqa: the latent, then the shared rope key
+        "w_kv_a": init_dense(keys[1], d, m.kv_lora_rank + m.rope_head_dim),
+        "kv_norm": init_rmsnorm(m.kv_lora_rank),
+        # kv_b_proj, split into its key and value halves
         "w_uk": init_dense(keys[2], m.kv_lora_rank, H * m.nope_head_dim),
         "w_uv": init_dense(keys[3], m.kv_lora_rank, H * m.v_head_dim),
-        "w_q": init_dense(keys[4], d, H * (m.nope_head_dim + m.rope_head_dim)),
-        "wo": init_dense(keys[5], H * m.v_head_dim, d),
+        "wo": init_dense(keys[4], H * m.v_head_dim, d),
     }
-    return p
 
 
-def _mla_qkv(params, cfg, x, positions):
+def _mla_query(params, cfg, x, positions):
+    """(q_nope (B,S,H,nope), roped q_pe (B,S,H,rope))."""
+    m = cfg.mla
+    B, S, _ = x.shape
+    q = dense(params["w_q"], x).reshape(
+        B, S, cfg.num_heads, m.nope_head_dim + m.rope_head_dim)
+    q_nope, q_pe = jnp.split(q, [m.nope_head_dim], axis=-1)
+    return q_nope, apply_rope(q_pe, positions, cfg.rope_theta, cfg.yarn)
+
+
+def _mla_rows(params, cfg, x, positions):
+    """(B, S, mla_row_width) cache rows: the normed latent, the roped
+    shared key, zeros."""
+    m = cfg.mla
+    B, S, _ = x.shape
+    kv = dense(params["w_kv_a"], x)
+    c, k_pe = jnp.split(kv, [m.kv_lora_rank], axis=-1)
+    c = rmsnorm(params["kv_norm"], c)
+    k_pe = apply_rope(k_pe[:, :, None], positions, cfg.rope_theta,
+                      cfg.yarn)[:, :, 0]
+    pad = mla_row_width(cfg) - m.kv_lora_rank - m.rope_head_dim
+    return jnp.concatenate([c, k_pe, jnp.zeros((B, S, pad), c.dtype)], -1)
+
+
+def _mla_expanded(params, cfg, x, positions, window):
+    """Causal self-attention in the expanded form. Returns the attention
+    output before ``wo``, (B, S, H * v), and the cache rows."""
     m = cfg.mla
     B, S, _ = x.shape
     H = cfg.num_heads
-    q = dense(params["w_q"], x).reshape(B, S, H, m.nope_head_dim + m.rope_head_dim)
-    q_nope, q_rope = jnp.split(q, [m.nope_head_dim], axis=-1)
-    q_rope = apply_rope(q_rope, positions, cfg.rope_theta)
-    c_kv = dense(params["w_dkv"], x)                               # (B,S,r)
-    k_rope = dense(params["w_krope"], x).reshape(B, S, 1, m.rope_head_dim)
-    k_rope = apply_rope(k_rope, positions, cfg.rope_theta)
-    return q_nope, q_rope, c_kv, k_rope
-
-
-def _mla_expand(params, cfg, c_kv):
-    m = cfg.mla
-    B, S, _ = c_kv.shape
-    H = cfg.num_heads
-    k_nope = dense(params["w_uk"], c_kv).reshape(B, S, H, m.nope_head_dim)
-    v = dense(params["w_uv"], c_kv).reshape(B, S, H, m.v_head_dim)
-    return k_nope, v
-
-
-def mla_attention(params, cfg: ModelConfig, x, positions, *, window=0):
-    """Train/prefill MLA. Concatenated (nope‖rope) q/k fed to the shared
-    chunked-attention core; the rope key is broadcast across heads."""
-    m = cfg.mla
-    B, S, _ = x.shape
-    H = cfg.num_heads
-    q_nope, q_rope, c_kv, k_rope = _mla_qkv(params, cfg, x, positions)
-    k_nope, v = _mla_expand(params, cfg, c_kv)
-    q = jnp.concatenate([q_nope, q_rope], axis=-1)
+    q_nope, q_pe = _mla_query(params, cfg, x, positions)
+    rows = _mla_rows(params, cfg, x, positions)
+    c = rows[..., :m.kv_lora_rank]
+    k_pe = rows[..., m.kv_lora_rank:m.kv_lora_rank + m.rope_head_dim]
+    k_nope = dense(params["w_uk"], c).reshape(B, S, H, m.nope_head_dim)
+    v = dense(params["w_uv"], c).reshape(B, S, H, m.v_head_dim)
+    q = jnp.concatenate([q_nope, q_pe], axis=-1)
     k = jnp.concatenate([k_nope, jnp.broadcast_to(
-        k_rope, (B, S, H, m.rope_head_dim))], axis=-1)
-    # pad v to match head_dim for the shared core? core allows hd_v != hd_qk?
-    # chunked_attention assumes same hd for q/k and v shape (..., hd): we pass
-    # v with its own dim by calling the core with matching K=H (no GQA here).
-    out = chunked_attention(q, k, _pad_like(v, q.shape[-1]),
-                            causal=True, window=window)[..., :m.v_head_dim]
-    return dense(params["wo"], out.reshape(B, S, H * m.v_head_dim))
+        k_pe[:, :, None], (B, S, H, m.rope_head_dim))], axis=-1)
+    out = chunked_attention(q, k, _pad_like(v, q.shape[-1]), causal=True,
+                            window=window, scale=mla_softmax_scale(cfg))
+    return out[..., :m.v_head_dim].reshape(B, S, H * m.v_head_dim), rows
 
 
 def _pad_like(v, hd):
@@ -419,45 +453,124 @@ def _pad_like(v, hd):
     return jnp.pad(v, ((0, 0),) * (v.ndim - 1) + ((0, hd - v.shape[-1]),))
 
 
-def init_mla_cache(cfg: ModelConfig, batch: int, max_len: int, dtype=jnp.bfloat16):
-    m = cfg.mla
-    return {
-        "c_kv": jnp.zeros((batch, max_len, m.kv_lora_rank), dtype),
-        "k_rope": jnp.zeros((batch, max_len, m.rope_head_dim), dtype),
-    }
+def mla_attention(params, cfg: ModelConfig, x, positions, *, window=0):
+    """Train/prefill MLA (causal)."""
+    out, _ = _mla_expanded(params, cfg, x, positions, window)
+    return dense(params["wo"], out)
+
+
+def init_mla_cache(cfg: ModelConfig, batch: int, max_len: int,
+                   dtype=jnp.bfloat16):
+    return {"latent": jnp.zeros((batch, max_len, mla_row_width(cfg)), dtype)}
 
 
 def mla_prefill(params, cfg: ModelConfig, x, positions, cache, *, window=0):
-    out = mla_attention(params, cfg, x, positions, window=window)
-    q_nope, q_rope, c_kv, k_rope = _mla_qkv(params, cfg, x, positions)
+    """Prefill: attend in the expanded form AND write the latent rows into
+    the cache (from position 0)."""
+    with jax.named_scope("attn.causal"):
+        out, rows = _mla_expanded(params, cfg, x, positions, window)
     cache = dict(cache)
-    cache["c_kv"] = jax.lax.dynamic_update_slice(
-        cache["c_kv"], c_kv.astype(cache["c_kv"].dtype), (0, 0, 0))
-    cache["k_rope"] = jax.lax.dynamic_update_slice(
-        cache["k_rope"], k_rope[:, :, 0].astype(cache["k_rope"].dtype), (0, 0, 0))
-    return out, cache
+    with jax.named_scope("attn.kv_write"):
+        cache["latent"] = jax.lax.dynamic_update_slice(
+            cache["latent"], rows.astype(cache["latent"].dtype), (0, 0, 0))
+    with jax.named_scope("attn.out"):
+        return dense(params["wo"], out), cache
 
 
-def mla_decode(params, cfg: ModelConfig, x, cache, cache_len, *, window=0):
-    """Decode with the compressed cache, expanding K/V on the fly."""
+def _mla_absorbed_query(params, cfg, x, positions):
+    """(B, H, mla_row_width) decode queries over the rows: q_nope . W_uk^T,
+    then the roped q_pe, then zeros. x: (B, 1, d)."""
     m = cfg.mla
     B = x.shape[0]
     H = cfg.num_heads
+    with jax.named_scope("attn.qkv"):
+        q_nope, q_pe = _mla_query(params, cfg, x, positions)
+    with jax.named_scope("attn.absorb"):
+        w_uk = params["w_uk"]["w"].reshape(m.kv_lora_rank, H, m.nope_head_dim)
+        q_lat = jnp.einsum("bhn,rhn->bhr", q_nope[:, 0], w_uk.astype(x.dtype))
+        pad = mla_row_width(cfg) - m.kv_lora_rank - m.rope_head_dim
+        return jnp.concatenate(
+            [q_lat, q_pe[:, 0], jnp.zeros((B, H, pad), x.dtype)], -1)
+
+
+def _mla_absorbed_out(params, cfg, lat):
+    """Attended latent (B, H, r) -> (B, 1, d): W_uv, then ``wo``."""
+    m = cfg.mla
+    B, H, _ = lat.shape
+    with jax.named_scope("attn.absorb"):
+        w_uv = params["w_uv"]["w"].reshape(m.kv_lora_rank, H, m.v_head_dim)
+        o = jnp.einsum("bhr,rhv->bhv", lat, w_uv.astype(lat.dtype))
+    with jax.named_scope("attn.out"):
+        return dense(params["wo"], o.reshape(B, 1, H * m.v_head_dim))
+
+
+def mla_decode(params, cfg: ModelConfig, x, cache, cache_len, *, window=0):
+    """Decode one token over a linear latent cache, absorbed form. x: (B,
+    1, d); cache_len: length BEFORE this token (a scalar)."""
+    m = cfg.mla
+    B = x.shape[0]
     positions = jnp.full((B, 1), cache_len, jnp.int32)
-    q_nope, q_rope, c_kv_new, k_rope_new = _mla_qkv(params, cfg, x, positions)
+    q = _mla_absorbed_query(params, cfg, x, positions)
     cache = dict(cache)
-    cache["c_kv"] = jax.lax.dynamic_update_slice_in_dim(
-        cache["c_kv"], c_kv_new.astype(cache["c_kv"].dtype), cache_len, axis=1)
-    cache["k_rope"] = jax.lax.dynamic_update_slice_in_dim(
-        cache["k_rope"], k_rope_new[:, :, 0].astype(cache["k_rope"].dtype),
-        cache_len, axis=1)
-    S_max = cache["c_kv"].shape[1]
-    k_nope, v = _mla_expand(params, cfg, cache["c_kv"].astype(x.dtype))
-    k_rope_all = jnp.broadcast_to(cache["k_rope"][:, :, None, :].astype(x.dtype),
-                                  (B, S_max, H, m.rope_head_dim))
-    k = jnp.concatenate([k_nope, k_rope_all], axis=-1)
-    q = jnp.concatenate([q_nope, q_rope], axis=-1).reshape(B, 1, H, -1)
-    out = decode_attention(q, k, _pad_like(v, q.shape[-1]),
-                           cache_len=cache_len + 1, window=window)
-    out = out[..., :m.v_head_dim]
-    return dense(params["wo"], out.reshape(B, 1, H * m.v_head_dim)), cache
+    rows = _mla_rows(params, cfg, x, positions)
+    cache["latent"] = jax.lax.dynamic_update_slice_in_dim(
+        cache["latent"], rows.astype(cache["latent"].dtype), cache_len, axis=1)
+    lat = cache["latent"].astype(x.dtype)                        # (B, S, D)
+    s = jnp.einsum("bhd,bsd->bhs", q, lat,
+                   preferred_element_type=jnp.float32)
+    s = s * mla_softmax_scale(cfg)
+    pos = jnp.arange(lat.shape[1])
+    mask = pos < cache_len + 1
+    if window > 0:
+        mask = mask & (pos >= cache_len + 1 - window)
+    p = jax.nn.softmax(jnp.where(mask[None, None], s, NEG_INF), axis=-1)
+    out = jnp.einsum("bhs,bsr->bhr", p, lat[..., :m.kv_lora_rank]
+                     .astype(jnp.float32)).astype(x.dtype)
+    return _mla_absorbed_out(params, cfg, out), cache
+
+
+def mla_decode_paged(params, cfg: ModelConfig, x, pool, block_tables,
+                     lengths, *, mesh=None):
+    """Continuous-batching decode over a paged latent pool, absorbed form.
+
+    pool["latent"]: (N_blocks, block, mla_row_width) — one row per token
+    (block 0 the reserved null block); block_tables, lengths and the
+    inactive-slot rule as in ``gqa_decode_paged``. ``cfg.paged_attn_impl``
+    "fused" runs the Pallas latent kernel (``kernels.latent_attention``)
+    straight off the pool, "gather" its jnp oracle over the gathered view.
+    """
+    from repro.kernels import latent_attention as _latent
+    from repro.kernels import ops as _kernel_ops
+    from repro.kernels import ref as _kernel_ref
+
+    m = cfg.mla
+    B = x.shape[0]
+    lat_pool = pool["latent"]
+    N, bs, D = lat_pool.shape
+    lengths = jnp.asarray(lengths, jnp.int32)
+    positions = lengths[:, None]                                  # (B, 1)
+    q = _mla_absorbed_query(params, cfg, x, positions)
+    with jax.named_scope("attn.kv_write"):
+        rows = _mla_rows(params, cfg, x, positions)[:, 0]
+        b_idx = jnp.arange(B)
+        blk = block_tables[b_idx, positions[:, 0] // bs]          # (B,)
+        off = positions[:, 0] % bs
+        active = (lengths > 0)[:, None]
+        lat_pool = lat_pool.at[blk, off].set(
+            jnp.where(active, rows.astype(lat_pool.dtype), lat_pool[blk, off]))
+    scale = mla_softmax_scale(cfg)
+    with jax.named_scope("attn.latent"):
+        if getattr(cfg, "paged_attn_impl", "fused") == "fused":
+            out = _kernel_ops.latent_decode_attention(
+                q, lat_pool, block_tables, lengths, r=m.kv_lora_rank,
+                scale=scale, mesh=mesh)
+        else:
+            out = _kernel_ref.latent_decode_ref(
+                q, lat_pool[block_tables].reshape(B, -1, D), lengths,
+                r=m.kv_lora_rank, scale=scale,
+                chunk=_latent.PAGES_PER_STEP * bs)
+    if mesh is not None:
+        from jax.sharding import NamedSharding, PartitionSpec as P
+        lat_pool = jax.lax.with_sharding_constraint(
+            lat_pool, NamedSharding(mesh, P()))
+    return _mla_absorbed_out(params, cfg, out), {**pool, "latent": lat_pool}

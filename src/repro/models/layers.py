@@ -99,17 +99,51 @@ def unembed(params, x):
 # Rotary position embeddings
 # ---------------------------------------------------------------------------
 
-def rope_frequencies(head_dim: int, theta: float = 10000.0):
-    return 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
+def rope_frequencies(head_dim: int, theta: float = 10000.0, yarn=None):
+    """Inverse frequencies of the rotary pairs, (head_dim/2,). ``yarn``: a
+    ``configs.base.YarnConfig`` — DeepSeek-V2's YaRN scaling
+    (``DeepseekV2YarnRotaryEmbedding``): pairs that turn more than
+    ``beta_fast`` times over the original context keep their frequency,
+    pairs that turn fewer than ``beta_slow`` times are slowed by
+    ``factor``, with a linear ramp between the two."""
+    base = 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32)
+                            / head_dim))
+    if yarn is None:
+        return base
+    def dim_of(turns):
+        return (head_dim * math.log(yarn.original_max_position
+                                    / (turns * 2 * math.pi))
+                / (2 * math.log(theta)))
+    low = max(math.floor(dim_of(yarn.beta_fast)), 0)
+    high = min(math.ceil(dim_of(yarn.beta_slow)), head_dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = jnp.clip((jnp.arange(head_dim // 2, dtype=jnp.float32) - low)
+                    / (high - low), 0.0, 1.0)
+    keep = 1.0 - ramp                    # 1: extrapolate, 0: interpolate
+    return base / yarn.factor * (1.0 - keep) + base * keep
 
 
-def apply_rope(x, positions, theta: float = 10000.0):
-    """x: (..., seq, heads, head_dim); positions: (..., seq)."""
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention temperature: 0.1 * mscale * ln(factor) + 1."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def apply_rope(x, positions, theta: float = 10000.0, yarn=None):
+    """x: (..., seq, heads, head_dim); positions: (..., seq). Rotate-half
+    pairing (first and second half of each head). ``yarn``: YaRN-scaled
+    frequencies, and cos/sin scaled by ``mscale(mscale) /
+    mscale(mscale_all_dim)`` (1 where the two are equal)."""
     head_dim = x.shape[-1]
-    freqs = rope_frequencies(head_dim, theta)                      # (hd/2,)
+    freqs = rope_frequencies(head_dim, theta, yarn)                # (hd/2,)
     angles = positions[..., :, None].astype(jnp.float32) * freqs   # (..., seq, hd/2)
     angles = angles[..., None, :]                                  # (..., seq, 1, hd/2)
     cos, sin = jnp.cos(angles), jnp.sin(angles)
+    if yarn is not None:
+        ratio = (yarn_mscale(yarn.factor, yarn.mscale)
+                 / yarn_mscale(yarn.factor, yarn.mscale_all_dim))
+        if ratio != 1.0:
+            cos, sin = cos * ratio, sin * ratio
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return out.astype(x.dtype)

@@ -18,6 +18,7 @@ MoE layers run one of three paths, selected by ``Runtime``:
 
 from __future__ import annotations
 
+import dataclasses
 from typing import Any, Dict, NamedTuple, Optional
 
 import jax
@@ -121,6 +122,12 @@ def _init_layer(key, cfg: ModelConfig, kind: str):
     return p
 
 
+def dense_layer_cfg(cfg: ModelConfig) -> ModelConfig:
+    """The config of the leading dense layers (``cfg.first_k_dense``):
+    the model's, with a dense FFN of width ``d_ff`` in place of experts."""
+    return dataclasses.replace(cfg, moe=None, first_k_dense=0)
+
+
 def _layer_kind(cfg: ModelConfig, layer_idx: int) -> str:
     if cfg.family == "ssm":
         return "rwkv"
@@ -155,8 +162,12 @@ def init_model(key, cfg: ModelConfig, dtype=jnp.float32):
             for i in range(cfg.num_layers)]
     else:
         kind = _layer_kind(cfg, 0)
-        layer_keys = jax.random.split(keys[2], cfg.num_layers)
+        layer_keys = jax.random.split(keys[2], cfg.num_moe_layers)
         params["layers"] = stack(layer_keys, kind)
+        if cfg.first_k_dense:
+            params["dense_layers"] = stack(
+                jax.random.split(keys[4], cfg.first_k_dense), kind,
+                dense_layer_cfg(cfg))
 
     if cfg.is_encdec:
         enc = cfg.encoder
@@ -203,12 +214,14 @@ def cache_len_for(cfg: ModelConfig, rt: Runtime, max_len: int) -> int:
 
 def init_cache(cfg: ModelConfig, rt: Runtime, batch: int, max_len: int,
                dtype=jnp.bfloat16):
-    """Stacked (over layers) cache pytree for prefill/decode."""
+    """Stacked (over layers) cache pytree for prefill/decode. The leading
+    dense layers' caches (``cfg.first_k_dense``) sit under ``"dense"``,
+    stacked over those layers alone."""
     L = cfg.num_layers
     clen = cache_len_for(cfg, rt, max_len)
 
-    def stack(tree):
-        return jax.tree.map(lambda a: jnp.broadcast_to(a, (L,) + a.shape), tree)
+    def stack(tree, n=L):
+        return jax.tree.map(lambda a: jnp.broadcast_to(a, (n,) + a.shape), tree)
 
     if cfg.family == "ssm":
         return stack(rwkv6.init_rwkv_state(cfg, batch))
@@ -223,8 +236,12 @@ def init_cache(cfg: ModelConfig, rt: Runtime, batch: int, max_len: int,
                     cfg, batch, min(max_len, cfg.local_window), dtype))
         return caches
     if cfg.attention == "mla":
-        return stack(attn.init_mla_cache(cfg, batch, clen, dtype))
-    c = stack(attn.init_gqa_cache(cfg, batch, clen, dtype))
+        one = attn.init_mla_cache(cfg, batch, clen, dtype)
+    else:
+        one = attn.init_gqa_cache(cfg, batch, clen, dtype)
+    c = stack(one, cfg.num_moe_layers)
+    if cfg.first_k_dense:
+        c = {**c, "dense": stack(one, cfg.first_k_dense)}
     if cfg.is_encdec:
         enc = cfg.encoder
         c = {"self": c,
@@ -396,7 +413,8 @@ def moe_apply(layer_p, cfg: ModelConfig, x, rt: Runtime, plan_l,
       predicted_l, token_weight, slot_w_l, resched_l, migration_l)
 
     if "shared" in layer_p["moe"]:
-        y = y + ffn(layer_p["moe"]["shared"], x, cfg.activation)
+        with jax.named_scope("moe.shared"):
+            y = y + ffn(layer_p["moe"]["shared"], x, cfg.activation)
     if "dense" in layer_p["moe"]:
         y = y + ffn(layer_p["moe"]["dense"], x, cfg.activation)
     return y, counts, slot_counts, aux, z, dropped, overflow
@@ -445,10 +463,15 @@ def _attn_layer(layer_p, cfg, x, positions, rt, *, cache=None, cache_len=None,
             # sliding window, or paged decode diverges from windowed
             # serving. cfg.paged_attn_impl selects the fused Pallas
             # one-pass kernel or the materialize-then-attend gather oracle
-            a, sub = attn.gqa_decode_paged(layer_p["attn"], cfg, h, sub,
-                                           block_tables, cache_len,
-                                           window=cfg.sliding_window,
-                                           mesh=rt.mesh)
+            if cfg.attention == "mla":
+                a, sub = attn.mla_decode_paged(layer_p["attn"], cfg, h, sub,
+                                               block_tables, cache_len,
+                                               mesh=rt.mesh)
+            else:
+                a, sub = attn.gqa_decode_paged(layer_p["attn"], cfg, h, sub,
+                                               block_tables, cache_len,
+                                               window=cfg.sliding_window,
+                                               mesh=rt.mesh)
         elif cfg.attention == "mla":
             a, sub = attn.mla_decode(layer_p["attn"], cfg, h, sub, cache_len,
                                      window=window)
@@ -486,7 +509,8 @@ def _attn_layer(layer_p, cfg, x, positions, rt, *, cache=None, cache_len=None,
             slot_w_l=slot_w_l, resched_l=resched_l, migration_l=migration_l)
         stats = (counts, slots, aux, z, dropped, overflow)
     else:
-        y = ffn(layer_p["ffn"], h, cfg.activation)
+        with jax.named_scope("ffn.dense"):
+            y = ffn(layer_p["ffn"], h, cfg.activation)
         stats = _zero_stats(cfg)
     return x + y, new_cache, stats
 
@@ -700,6 +724,29 @@ def forward(params, cfg: ModelConfig, batch, rt: Runtime, *, mode: str,
                    and slot_ready is not None and target_plan is not None
                    and plan is not None)
 
+        dense_cache = None
+        if cfg.first_k_dense:
+            # the leading dense layers: a scan of their own, over their own
+            # params and caches, ahead of the uniform stack
+            dcfg = dense_layer_cfg(cfg)
+            if cache is not None:
+                cache = dict(cache)
+                dense_cache = cache.pop("dense")
+
+            def dense_body(h, xs):
+                layer_p, cache_l = xs
+                with jax.named_scope("layer.body"):
+                    h = constrain_acts(h, rt, seq_shard)
+                    h, new_c, _ = _attn_layer(
+                        layer_p, dcfg, h, positions, rt, cache=cache_l,
+                        cache_len=cache_len, mode=mode, enc_out=enc_out,
+                        block_tables=block_tables)
+                    return constrain_acts(h, rt, seq_shard), new_c
+
+            with jax.named_scope("layers"):
+                x, dense_cache = jax.lax.scan(
+                    dense_body, x, (params["dense_layers"], dense_cache))
+
         def body(h, xs):
             (layer_p, cache_l, plan_l, pred_l, slot_l, back_l, ready_l,
              tplan_l, resched_l) = xs
@@ -728,6 +775,8 @@ def forward(params, cfg: ModelConfig, batch, rt: Runtime, *, mode: str,
               resched if resched is not None else _none_stack(L))
         with jax.named_scope("layers"):
             x, (new_cache, layer_stats) = jax.lax.scan(body, x, xs)
+        if dense_cache is not None and new_cache is not None:
+            new_cache = {**new_cache, "dense": dense_cache}
         if cfg.is_moe:
             counts, slots, aux, z, dropped, overflow = layer_stats
             stats = {"expert_counts": counts, "slot_counts": slots,

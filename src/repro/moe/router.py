@@ -13,7 +13,7 @@ from repro.models.layers import truncated_normal_init
 
 class RouterOutput(NamedTuple):
     expert_idx: jnp.ndarray     # (T, K) int32
-    gates: jnp.ndarray          # (T, K) float32 (normalised over K)
+    gates: jnp.ndarray          # (T, K) float32 (see ``topk_gates``)
     probs: jnp.ndarray          # (T, E) full softmax (for aux losses / stats)
     aux_loss: jnp.ndarray       # scalar
     z_loss: jnp.ndarray         # scalar
@@ -21,6 +21,15 @@ class RouterOutput(NamedTuple):
 
 def init_router(key, d_model: int, moe: MoEConfig):
     return {"w": truncated_normal_init(key, (d_model, moe.num_experts), 0.02)}
+
+
+def topk_gates(top_probs, moe: MoEConfig):
+    """Gates of the k chosen experts from their softmax probabilities,
+    renormalised over the k where ``moe.norm_topk_prob``."""
+    if moe.norm_topk_prob:
+        top_probs = top_probs / jnp.maximum(
+            top_probs.sum(-1, keepdims=True), 1e-9)
+    return top_probs
 
 
 def route(params, moe: MoEConfig, x, impl: str = "dense") -> RouterOutput:
@@ -38,14 +47,14 @@ def route(params, moe: MoEConfig, x, impl: str = "dense") -> RouterOutput:
         from repro.kernels import ops as kernel_ops
         expert_idx, gates, probs, lse, counts = kernel_ops.fused_topk_route(
             logits, moe.top_k)
-        gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
+        gates = topk_gates(gates, moe)
         f = counts.astype(jnp.float32) / expert_idx.size
         aux = E * jnp.sum(f * probs.mean(axis=0)) * moe.router_aux_loss
         z = jnp.mean(jnp.square(lse)) * moe.router_z_loss
         return RouterOutput(expert_idx, gates, probs, aux, z)
     probs = jax.nn.softmax(logits, axis=-1)
     gates, expert_idx = jax.lax.top_k(probs, moe.top_k)
-    gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
+    gates = topk_gates(gates, moe)
 
     # Switch-style load-balance loss: E * sum_e f_e * p_e
     f = jnp.zeros((E,), jnp.float32).at[expert_idx.reshape(-1)].add(

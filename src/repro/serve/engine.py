@@ -68,7 +68,7 @@ def _clamp_store_dup_slots(cfg: ModelConfig, params, ep_ranks: int,
     return clamp_dup_slots(
         cfg.moe.num_experts, ep_ranks, dup_slots,
         entry_bytes=_eb(params["layers"]["moe"]["experts"]),
-        num_layers=cfg.num_layers,
+        num_layers=cfg.num_moe_layers,
         hbm_budget_bytes=cfg.moe.store_hbm_budget_gb * 1e9)
 
 
@@ -105,7 +105,7 @@ class _OverlapStoreMixin:
                     jnp.asarray(self._executor.ready_mask()),
                     self._executor.target_plan)
         if self._idle_ready is None:
-            self._idle_ready = jnp.zeros((self.cfg.num_layers,), bool)
+            self._idle_ready = jnp.zeros((self.cfg.num_moe_layers,), bool)
         return self._store.weights, self._idle_ready, self._current_plan()
 
 
@@ -202,9 +202,9 @@ class ServeEngine(_OverlapStoreMixin):
                 max_copies=serve.max_copies)
             self.cfg = dataclasses.replace(cfg, moe=self.moe_cfg)
             self.estimator = DistributionEstimator(
-                cfg.num_layers, cfg.moe.num_experts, ema=serve.ema)
+                cfg.num_moe_layers, cfg.moe.num_experts, ema=serve.ema)
             self.accuracy = PredictorAccuracyTracker(
-                cfg.num_layers, cfg.moe.num_experts)
+                cfg.num_moe_layers, cfg.moe.num_experts)
         else:
             self.moe_cfg = None
             self.estimator = None
@@ -222,7 +222,7 @@ class ServeEngine(_OverlapStoreMixin):
         m = self.moe_cfg
         plans = [identity_plan(m.num_experts, self.ep_ranks,
                                m.duplication_slots, m.max_copies)
-                 for _ in range(self.cfg.num_layers)]
+                 for _ in range(self.cfg.num_moe_layers)]
         return stack_plans(plans)
 
     def replan(self) -> Optional[PlacementPlan]:
@@ -240,7 +240,7 @@ class ServeEngine(_OverlapStoreMixin):
             return self._plan_stack
         dist = self.estimator.predict()                  # (L, E)
         plans = []
-        for l in range(self.cfg.num_layers):
+        for l in range(self.cfg.num_moe_layers):
             res = duplicate_experts_host(dist[l], self.ep_ranks,
                                          m.duplication_slots, m.max_copies)
             plans.append(res.plan)
@@ -275,7 +275,7 @@ class ServeEngine(_OverlapStoreMixin):
         cap = capacity(t_local, m.top_k, n_slots_g,
                        m.capacity_factor) * self.ep_ranks
         layer_plans = [jax.tree.map(lambda a, l=l: np.asarray(a)[l], plan)
-                       for l in range(self.cfg.num_layers)]
+                       for l in range(self.cfg.num_moe_layers)]
         quota, results = self._resched_sched.plan_stack(
             counts, layer_plans, ep_ranks=self.ep_ranks,
             dup_slots=m.duplication_slots, cap=float(cap))
@@ -319,7 +319,7 @@ class ServeEngine(_OverlapStoreMixin):
             if self._overlap_on:
                 self._executor = LayerStagedExecutor(
                     self._migrate_fn, experts, self._store.entry_bytes,
-                    num_layers=self.cfg.num_layers,
+                    num_layers=self.cfg.num_moe_layers,
                     chunk=self.serve.migrate_chunk, tracer=self.tracer)
         return self._store.weights
 
@@ -654,8 +654,8 @@ class ContinuousEngine(_OverlapStoreMixin):
         if cfg.family in ("ssm", "hybrid") or cfg.is_encdec:
             raise ValueError(f"{cfg.family}: continuous batching supports "
                              "uniform-stack decoder-only architectures")
-        if cfg.attention != "gqa":
-            raise ValueError("paged KV cache is implemented for GQA")
+        if cfg.attention not in ("gqa", "mla"):
+            raise ValueError("paged KV cache is implemented for GQA and MLA")
         if cfg.sliding_window and ccfg.prefill_len > cfg.sliding_window:
             # decode applies the window as a mask over the linear pool, but
             # prefill runs full-causal within the bucket — exact only while
@@ -715,9 +715,9 @@ class ContinuousEngine(_OverlapStoreMixin):
             self.dup_slot_quota = dup_slots
             cfg = dataclasses.replace(cfg, moe=self.moe_cfg)
             self.estimator = DistributionEstimator(
-                cfg.num_layers, cfg.moe.num_experts, ema=ccfg.ema)
+                cfg.num_moe_layers, cfg.moe.num_experts, ema=ccfg.ema)
             self.accuracy = PredictorAccuracyTracker(
-                cfg.num_layers, cfg.moe.num_experts)
+                cfg.num_moe_layers, cfg.moe.num_experts)
         else:
             self.moe_cfg = None
             self.estimator = None
@@ -789,7 +789,7 @@ class ContinuousEngine(_OverlapStoreMixin):
             if self._overlap:
                 self._executor = LayerStagedExecutor(
                     self._migrate_fn, experts, self._store.entry_bytes,
-                    num_layers=cfg.num_layers, chunk=ccfg.migrate_chunk,
+                    num_layers=cfg.num_moe_layers, chunk=ccfg.migrate_chunk,
                     tracer=self.tracer)
             else:
                 self._executor = MigrationExecutor(
@@ -805,7 +805,7 @@ class ContinuousEngine(_OverlapStoreMixin):
         m = self.moe_cfg
         return stack_plans([
             identity_plan(m.num_experts, self.ep_ranks, m.duplication_slots,
-                          m.max_copies) for _ in range(self.cfg.num_layers)])
+                          m.max_copies) for _ in range(self.cfg.num_moe_layers)])
 
     def _current_plan(self) -> Optional[PlacementPlan]:
         if self._plan_stack is None:
@@ -835,7 +835,7 @@ class ContinuousEngine(_OverlapStoreMixin):
         if q == m.duplication_slots:
             plans = [duplicate_experts_host(
                 dist[l], self.ep_ranks, m.duplication_slots,
-                m.max_copies).plan for l in range(self.cfg.num_layers)]
+                m.max_copies).plan for l in range(self.cfg.num_moe_layers)]
         else:
             # quota-limited: plan with only q replica slots, then rebuild
             # at the FULL compiled geometry so no traced shape changes
@@ -843,7 +843,7 @@ class ContinuousEngine(_OverlapStoreMixin):
                 duplicate_experts_host(dist[l], self.ep_ranks, q,
                                        m.max_copies).assignments,
                 m.num_experts, self.ep_ranks, m.duplication_slots,
-                m.max_copies, quota=q) for l in range(self.cfg.num_layers)]
+                m.max_copies, quota=q) for l in range(self.cfg.num_moe_layers)]
         out = self._adopt_plan(stack_plans(plans))
         if self.lever == "reschedule":
             self._resched_frozen = True
@@ -888,7 +888,7 @@ class ContinuousEngine(_OverlapStoreMixin):
         cap = capacity(t_local, m.top_k, n_slots_g,
                        m.capacity_factor) * self.ep_ranks
         layer_plans = [jax.tree.map(lambda a, l=l: np.asarray(a)[l], plan)
-                       for l in range(self.cfg.num_layers)]
+                       for l in range(self.cfg.num_moe_layers)]
         quota, results = self._resched_sched.plan_stack(
             counts, layer_plans, ep_ranks=self.ep_ranks,
             dup_slots=m.duplication_slots, cap=float(cap))
@@ -925,7 +925,7 @@ class ContinuousEngine(_OverlapStoreMixin):
         if self._recent_step_s > 0:
             return self._recent_step_s
         per_layer = self.metrics.phase_times.get("total", 0.0)
-        return per_layer * self.cfg.num_layers
+        return per_layer * self.cfg.num_moe_layers
 
     def _overlap_budget(self) -> int:
         from repro.runtime import overlap_chunk_budget
@@ -1095,7 +1095,7 @@ class ContinuousEngine(_OverlapStoreMixin):
         prefetch controller can pre-begin migration toward the plan the
         next re-plan will most likely produce."""
         E = self.moe_cfg.num_experts
-        L = self.cfg.num_layers
+        L = self.cfg.num_moe_layers
         ids = np.clip(np.asarray(pred).reshape(L, -1), 0, E - 1)
         hist = np.stack([np.bincount(ids[l], minlength=E)
                          for l in range(L)]).astype(np.float64)
@@ -1134,7 +1134,7 @@ class ContinuousEngine(_OverlapStoreMixin):
         target = stack_plans([
             duplicate_experts_host(dist[l], self.ep_ranks,
                                    m.duplication_slots, m.max_copies).plan
-            for l in range(self.cfg.num_layers)])
+            for l in range(self.cfg.num_moe_layers)])
         diff = plan_diff(self._plan_stack, target, self.ep_ranks,
                          m.duplication_slots)
         if diff.num_entries == 0:
@@ -1177,7 +1177,7 @@ class ContinuousEngine(_OverlapStoreMixin):
             # a runtime lever switch (controller or config) never recompiles
             from repro.schedule import even_quota_stack
             rescheds.append(jnp.asarray(even_quota_stack(
-                self.cfg.num_layers, jax.tree.map(lambda a: np.asarray(a)[0],
+                self.cfg.num_moe_layers, jax.tree.map(lambda a: np.asarray(a)[0],
                                                   plan))))
         slot_w = self._store.weights if self._store is not None else None
         if self._migrate_fn is not None:
@@ -1259,12 +1259,12 @@ class ContinuousEngine(_OverlapStoreMixin):
             jnp.asarray(np.zeros((1, ccfg.prefill_len), np.float32)),
             jnp.asarray(np.zeros((1, ccfg.prefill_len), np.int32)),
             # the overlapped-migration ready mask (np bool (L,) -> device)
-            jnp.asarray(np.zeros((self.cfg.num_layers,), bool)),
-            jnp.zeros((self.cfg.num_layers,), bool),
+            jnp.asarray(np.zeros((self.cfg.num_moe_layers,), bool)),
+            jnp.zeros((self.cfg.num_moe_layers,), bool),
         ) + ((
             # the np int32 quota-stack -> device conversion (re-plans build
             # quotas on the host every boundary)
-            jnp.asarray(np.zeros((self.cfg.num_layers,
+            jnp.asarray(np.zeros((self.cfg.num_moe_layers,
                                   self.moe_cfg.num_experts,
                                   self.moe_cfg.max_copies), np.int32)),
         ) if self._resched_enabled else ()))
@@ -1371,7 +1371,7 @@ class ContinuousEngine(_OverlapStoreMixin):
                     d_model=self.cfg.d_model, d_ff=m.d_ff_expert,
                     num_experts=m.num_experts, ranks=self.ep_ranks,
                     dup_slots=m.duplication_slots,
-                    layers=self.cfg.num_layers,
+                    layers=self.cfg.num_moe_layers,
                     chunk=self.ccfg.migrate_chunk, iters=iters))
         if not phases:
             return {}

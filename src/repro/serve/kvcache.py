@@ -1,9 +1,10 @@
 """Paged (block-pool) KV cache for continuous batching.
 
 The device side is ONE fixed-shape pool per layer stack —
-``{"k","v"}: (L, num_blocks, block_size, K, hd)`` — so every jitted step
-sees static shapes no matter how requests join, leave, grow, or get
-preempted. The host side is a free-list allocator plus per-slot block
+``{"k","v"}: (L, num_blocks, block_size, K, hd)`` for GQA, one latent row
+per token ``{"latent": (L, num_blocks, block_size, D)}`` for MLA — so
+every jitted step sees static shapes no matter how requests join, leave,
+grow, or get preempted. The host side is a free-list allocator plus per-slot block
 tables (``(max_slots, max_blocks_per_slot)`` int32) that map each slot's
 logical positions onto physical blocks.
 
@@ -29,40 +30,58 @@ NULL_BLOCK = 0
 
 def pool_sharding(cfg: ModelConfig, mesh):
     """The pool's fixed layout on ``mesh`` (None without one): KV heads
-    split like the attention projections, blocks whole on every shard."""
+    split like the attention projections, blocks whole on every shard; a
+    latent pool (MLA, no head dim) whole on every device."""
     if mesh is None:
         return None
-    from jax.sharding import NamedSharding
+    from jax.sharding import NamedSharding, PartitionSpec as P
     from repro.sharding import paged_pool_spec
+    if cfg.attention == "mla":
+        return NamedSharding(mesh, P())
     return NamedSharding(mesh, paged_pool_spec(mesh, cfg.num_kv_heads,
                                                stacked=True))
 
 
 def init_block_pool(cfg: ModelConfig, num_blocks: int, block_size: int,
                     dtype=jnp.bfloat16, mesh=None) -> Dict[str, jnp.ndarray]:
-    """Device-side pool, created in place in its ``pool_sharding``.
-    Requires a uniform-stack GQA architecture (the continuous engine
-    asserts this)."""
-    L, K, hd = cfg.num_layers, cfg.num_kv_heads, cfg.head_dim
-    shape = (L, num_blocks, block_size, K, hd)
-    return jax.jit(lambda: {"k": jnp.zeros(shape, dtype),
-                            "v": jnp.zeros(shape, dtype)},
-                   out_shardings=pool_sharding(cfg, mesh))()
+    """Device-side pool, created in place in its ``pool_sharding``: GQA
+    ``{"k","v"}: (L, N, bs, K, hd)``, MLA ``{"latent": (L, N, bs, D)}``
+    (one row per token, ``attention.mla_row_width``). ``L`` is the uniform
+    stack's depth; leading dense layers (``cfg.first_k_dense``) get a pool
+    of their own under ``"dense"``, as ``init_cache`` lays out the linear
+    cache. Requires a uniform-stack decoder (the continuous engine asserts
+    this)."""
+    if cfg.attention == "mla":
+        from repro.models.attention import mla_row_width
+        row = {"latent": (num_blocks, block_size, mla_row_width(cfg))}
+    else:
+        kv = (num_blocks, block_size, cfg.num_kv_heads, cfg.head_dim)
+        row = {"k": kv, "v": kv}
+
+    def make():
+        pool = {n: jnp.zeros((cfg.num_moe_layers,) + s, dtype)
+                for n, s in row.items()}
+        if cfg.first_k_dense:
+            pool["dense"] = {n: jnp.zeros((cfg.first_k_dense,) + s, dtype)
+                             for n, s in row.items()}
+        return pool
+    return jax.jit(make, out_shardings=pool_sharding(cfg, mesh))()
 
 
 def write_prefill_blocks(pool: Dict[str, jnp.ndarray],
                          temp: Dict[str, jnp.ndarray],
                          table: jnp.ndarray) -> Dict[str, jnp.ndarray]:
-    """Scatter a prefilled (L, 1, S_pad, K, hd) linear cache into the pool.
+    """Scatter a prefilled linear cache (each leaf (L, 1, S_pad, ...)) into
+    the pool (each leaf (L, N, bs, ...)).
 
     ``table``: (S_pad // block_size,) physical-block ids (traced). Entries
     past the request's allocation are NULL_BLOCK — those writes land in
     the null block and are never read. jit this once per prefill bucket.
     """
     def upd(p, t):
-        L, _, S, K, hd = t.shape
+        L, _, S = t.shape[:3]
         bs = p.shape[2]
-        blocks = t.reshape(L, S // bs, bs, K, hd)
+        blocks = t.reshape((L, S // bs, bs) + t.shape[3:])
         return p.at[:, table].set(blocks.astype(p.dtype))
     with jax.named_scope("attn.kv_write"):
         return jax.tree.map(upd, pool, temp)

@@ -31,7 +31,7 @@ _RULES = [
     (r"(wq|wk|wv|w_q)/w$", (None, "model")),
     (r"(wo|w_o)/w$", ("model", None)),
     (r"(w_uk|w_uv)/w$", (None, "model")),          # MLA up-projections: head-sharded
-    (r"(w_dkv|w_krope)/w$", (None, None)),
+    (r"w_kv_a/w$", (None, None)),                   # MLA latent down-projection
     (r"experts/w_gate$", ("model", None, None)),
     (r"experts/w_up$", ("model", None, None)),
     (r"experts/w_down$", ("model", None, None)),
@@ -136,7 +136,8 @@ _EXPERT_TP_RULES = [
 ]
 
 
-def param_specs(params, stacked_prefixes=("layers", "enc_layers", "dec_layers"),
+def param_specs(params, stacked_prefixes=("layers", "dense_layers",
+                                          "enc_layers", "dec_layers"),
                 fsdp_axes=(), fsdp_size: int = 1, mesh: Optional[Mesh] = None,
                 expert_tp_axes=()):
     """PartitionSpec pytree matching ``params``. Leaves under a stacked

@@ -44,11 +44,13 @@ def test_forward_shapes_and_finite(arch):
     assert logits.shape == (B, S_out, cfg.vocab_size)
     assert bool(jnp.isfinite(logits.astype(jnp.float32)).all())
     if cfg.is_moe:
+        # one row per layer of the MoE stack (leading dense layers hold
+        # no experts)
         counts = stats["expert_counts"]
-        assert counts.shape == (cfg.num_layers, cfg.moe.num_experts)
+        assert counts.shape == (cfg.num_moe_layers, cfg.moe.num_experts)
         # every routed (token, k) pair lands on exactly one expert
         assert float(counts.sum()) == pytest.approx(
-            cfg.num_layers * B * S_out * cfg.moe.top_k)
+            cfg.num_moe_layers * B * S_out * cfg.moe.top_k)
 
 
 @pytest.mark.parametrize("arch", ASSIGNED_ARCHS)

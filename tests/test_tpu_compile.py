@@ -201,3 +201,64 @@ def test_ep4_overlap_store_decode_step_compiles(topo, mosaic):
     assert 0 < per_chip < 16e9
     store_layer_bytes = 3 * D_MODEL * D_FF * 2 * (EXPERTS // 4 + 1)
     assert mem.temp_size_in_bytes < store_layer_bytes
+
+
+# DeepSeek-V2-Lite published widths: 16 heads over a 512 + 64 latent row
+# (padded to 640 lanes), the cell's 80 slots and 2,048 positions
+DS_HEADS, DS_ROW, DS_LATENT, DS_SLOTS, DS_MAX_LEN = 16, 640, 512, 80, 2048
+
+
+def test_latent_decode_kernel_compiles(one_chip, mosaic):
+    """The paged latent kernel's multi-page DMAs move whole 128-lane
+    tiles of a row (a 576-wide row would need an unaligned slice)."""
+    n_blocks = 1 + DS_SLOTS * (DS_MAX_LEN // BLOCK)
+    q = _sds((DS_SLOTS, DS_HEADS, DS_ROW), jnp.bfloat16, one_chip)
+    pool = _sds((n_blocks, BLOCK, DS_ROW), jnp.bfloat16, one_chip)
+    tables = _sds((DS_SLOTS, DS_MAX_LEN // BLOCK), jnp.int32, one_chip)
+    lengths = _sds((DS_SLOTS,), jnp.int32, one_chip)
+    _, text = _compile(partial(ops.latent_decode_attention, r=DS_LATENT,
+                               scale=0.1147), q, pool, tables, lengths)
+    assert "tpu_custom_call" in text
+
+
+def test_deepseek_v2_decode_step_compiles(topo, mosaic):
+    """The dense layer and one MoE layer of DeepSeek-V2-Lite at published
+    widths, served as the cell serves them (one chip, dropless capacity,
+    80 slots over the latent pool): the latent kernel runs in its
+    ``shard_map``, and weights, pool and temporaries fit the chip."""
+    from repro.core.placement import identity_plan, stack_plans
+    from repro.launch.mesh import make_dev_mesh
+    from repro.models.transformer import Runtime, init_model
+    from repro.serve.kvcache import init_block_pool, pool_sharding
+    from repro.sharding import make_shardings, param_specs
+    from repro.train.steps import make_paged_decode_step
+
+    base = get_config("deepseek-v2-lite-16b")
+    cfg = dataclasses.replace(base, num_layers=2, moe=dataclasses.replace(
+        base.moe, capacity_factor=64 / 6))
+    mesh = make_dev_mesh(1, 1, devices=topo.devices[:1])
+    rep = NamedSharding(mesh, P())
+    shapes = jax.eval_shape(
+        lambda k: init_model(k, cfg, jnp.bfloat16), jax.random.PRNGKey(0))
+    params = jax.tree.map(
+        lambda a, s: _sds(a.shape, a.dtype, s), shapes,
+        make_shardings(mesh, param_specs(shapes, mesh=mesh)))
+    plan = stack_plans([identity_plan(64, 1, 0, cfg.moe.max_copies)])
+    plan = jax.tree.map(lambda a: _sds(a.shape, a.dtype, rep), plan)
+    n_blocks = 1 + DS_SLOTS * (DS_MAX_LEN // BLOCK)
+    pool = jax.tree.map(
+        lambda a: _sds(a.shape, a.dtype, pool_sharding(cfg, mesh)),
+        jax.eval_shape(lambda: init_block_pool(cfg, n_blocks, BLOCK)))
+    rt = Runtime(mesh=mesh, ep=True, ep_ranks=1, window_override=DS_MAX_LEN)
+    with mesh:
+        compiled = jax.jit(make_paged_decode_step(cfg, rt)).lower(
+            params, _sds((DS_SLOTS, 1), jnp.int32, rep), pool,
+            _sds((DS_SLOTS, DS_MAX_LEN // BLOCK), jnp.int32, rep),
+            _sds((DS_SLOTS,), jnp.int32, rep), plan,
+            _sds((DS_SLOTS, 1), jnp.float32, rep)).compile()
+    text = compiled.as_text()
+    assert sum("tpu_custom_call" in ln and f"{DS_LATENT}]" in ln
+               for ln in text.splitlines()) >= 1
+    mem = compiled.memory_analysis()
+    assert 0 < (mem.argument_size_in_bytes + mem.output_size_in_bytes
+                + mem.temp_size_in_bytes) < 16e9
